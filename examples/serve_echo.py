@@ -6,25 +6,17 @@ with GENESYS network syscalls.
 import socket
 import threading
 
-import jax
 import numpy as np
 
 from repro.configs import get_config
 from repro.core.genesys import Genesys, GenesysConfig
-from repro.launch.mesh import make_host_mesh
+from repro.launch.serve import load_model
 from repro.models.registry import get_api
 from repro.serving.server import GenesysUdpServer
-from repro.sharding import rules_for
-from repro.train.steps import make_serve_step
 
 g = Genesys(GenesysConfig(n_workers=2))
-cfg = get_config("rwkv6-3b").reduced()
-mesh = make_host_mesh()
-rules = rules_for(cfg, mesh)
-api = get_api(cfg)
-params, _ = api.init(jax.random.PRNGKey(0), cfg)
-cache = api.init_cache(cfg, 1, 128)
-serve = jax.jit(make_serve_step(cfg, rules))
+model = load_model(get_config("rwkv6-3b").reduced())
+cache = get_api(model.cfg).init_cache(model.cfg, 1, 128)
 
 srv = GenesysUdpServer(g, port=0, payload=512)
 port = g.table._sockets[srv.fd].getsockname()[1]
@@ -34,10 +26,10 @@ client.bind(("127.0.0.1", 0))
 client.settimeout(30)
 cport = client.getsockname()[1]
 
-with mesh:
+with model.mesh:
     th = threading.Thread(
         target=srv.serve_model,
-        args=(serve, params, cache),
+        args=(model.serve_step, model.params, cache),
         kwargs=dict(n_batches=1, reply_port=cport, max_tokens=6),
         daemon=True)
     th.start()

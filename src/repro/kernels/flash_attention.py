@@ -21,6 +21,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels.decode_attention import resolve_interpret
+
 DEFAULT_BLOCK = 128
 NEG_INF = -1e30
 
@@ -77,7 +79,7 @@ def flash_attention_fwd(q, k, v, *, causal: bool = True,
                         scale: float | None = None,
                         block_q: int = DEFAULT_BLOCK,
                         block_k: int = DEFAULT_BLOCK,
-                        interpret: bool = True):
+                        interpret: bool | None = None):
     """q [B,H,Sq,hd]; k,v [B,KV,Skv,hd] -> (o [B,H,Sq,hd], lse [B,H,Sq])."""
     B, H, Sq, hd = q.shape
     _, KV, Skv, _ = k.shape
@@ -114,7 +116,7 @@ def flash_attention_fwd(q, k, v, *, causal: bool = True,
             pl_scratch((bq,)),
             pl_scratch((bq,)),
         ],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(q, k, v)
     return o, lse
 
@@ -205,7 +207,7 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 def flash_attention_bwd(q, k, v, o, lse, do, *, causal=True, scale=None,
                         block_q: int = DEFAULT_BLOCK,
                         block_k: int = DEFAULT_BLOCK,
-                        interpret: bool = True):
+                        interpret: bool | None = None):
     B, H, Sq, hd = q.shape
     _, KV, Skv, _ = k.shape
     G = H // KV
@@ -233,7 +235,7 @@ def flash_attention_bwd(q, k, v, o, lse, do, *, causal=True, scale=None,
         out_specs=pl.BlockSpec((1, 1, bq, hd), lambda b, h, i, j: (b, h, i, 0)),
         out_shape=jax.ShapeDtypeStruct((B, H, Sq, hd), q.dtype),
         scratch_shapes=[pl_scratch((bq, hd))],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(q, k, v, do, lse, delta)
 
     dk, dv = pl.pallas_call(
@@ -261,6 +263,6 @@ def flash_attention_bwd(q, k, v, o, lse, do, *, causal=True, scale=None,
             jax.ShapeDtypeStruct((B, KV, Skv, hd), v.dtype),
         ],
         scratch_shapes=[pl_scratch((bk, hd)), pl_scratch((bk, hd))],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(q, k, v, do, lse, delta)
     return dq, dk, dv

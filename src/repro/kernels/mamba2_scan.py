@@ -20,6 +20,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.decode_attention import resolve_interpret
+
 
 def _kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, y_ref, sfin_ref, state, *,
             chunk: int, nc: int):
@@ -64,7 +66,8 @@ def _kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, y_ref, sfin_ref, state, *,
         sfin_ref[0, 0] = state[...].astype(sfin_ref.dtype)
 
 
-def mamba2_ssd(x, dt, A, Bm, Cm, *, chunk: int = 64, interpret: bool = True):
+def mamba2_ssd(x, dt, A, Bm, Cm, *, chunk: int = 64,
+               interpret: bool | None = None):
     """x [B,L,H,P]; dt [B,L,H]; A [H]; Bm,Cm [B,L,N]
     -> (y [B,L,H,P], state [B,H,N,P])."""
     B, L, H, P = x.shape
@@ -91,6 +94,6 @@ def mamba2_ssd(x, dt, A, Bm, Cm, *, chunk: int = 64, interpret: bool = True):
             jax.ShapeDtypeStruct((B, H, N, P), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((N, P), jnp.float32)],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(x, dt, A.astype(jnp.float32), Bm, Cm)
     return y, sfin

@@ -20,6 +20,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.decode_attention import resolve_interpret
+
 
 def _kernel(eids_ref, x_ref, w_ref, o_ref, acc, *, nk: int):
     kdim = pl.program_id(2)
@@ -38,7 +40,7 @@ def _kernel(eids_ref, x_ref, w_ref, o_ref, acc, *, nk: int):
 
 
 def gmm(x, w, tile_expert, *, tile_m: int = 128, tile_k: int = 128,
-        tile_n: int = 128, interpret: bool = True):
+        tile_n: int = 128, interpret: bool | None = None):
     """x [T, D] (sorted/padded by expert); w [E, D, F];
     tile_expert [T // tile_m] int32 -> out [T, F]."""
     T, D = x.shape
@@ -62,5 +64,5 @@ def gmm(x, w, tile_expert, *, tile_m: int = 128, tile_k: int = 128,
         functools.partial(_kernel, nk=nk),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((T, F), x.dtype),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(tile_expert, x, w)

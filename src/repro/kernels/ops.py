@@ -23,7 +23,7 @@ from repro.kernels import moe_gmm as _gm
 
 @partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
 def flash_attention(q, k, v, causal=True, block_q=128, block_k=128,
-                    interpret=True):
+                    interpret=None):
     o, _ = _fa.flash_attention_fwd(q, k, v, causal=causal, block_q=block_q,
                                    block_k=block_k, interpret=interpret)
     return o
@@ -86,18 +86,18 @@ def update_kv_buffer(k_pages, v_pages, k_new, v_new, slots):
     return kf.reshape(NB, BS, KV, hd), vf.reshape(NB, BS, KV, hd)
 
 
-def mamba2_ssd(x, dt, A, Bm, Cm, *, chunk=64, interpret=True):
+def mamba2_ssd(x, dt, A, Bm, Cm, *, chunk=64, interpret=None):
     return _ms.mamba2_ssd(x, dt, A, Bm, Cm, chunk=chunk, interpret=interpret)
 
 
-def rwkv6_wkv(r, k, v, w, u, *, chunk=64, interpret=True):
+def rwkv6_wkv(r, k, v, w, u, *, chunk=64, interpret=None):
     return _rs.rwkv6_wkv(r, k, v, w, u, chunk=chunk, interpret=interpret)
 
 
 # ---------------------------------------------------- grouped matmul -------
 
 def moe_gmm_apply(x, w, expert_of_token, *, n_experts: int, tile_m=128,
-                  interpret=True):
+                  interpret=None):
     """Ragged expert matmul with host-free sort/pad bookkeeping.
 
     x [T, D]; w [E, D, F]; expert_of_token [T] int32 -> [T, F] aligned with

@@ -18,6 +18,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.decode_attention import resolve_interpret
+
 
 def _kernel(r_ref, k_ref, v_ref, w_ref, u_ref, o_ref, sfin_ref, state, *,
             chunk: int, nc: int):
@@ -62,7 +64,8 @@ def _kernel(r_ref, k_ref, v_ref, w_ref, u_ref, o_ref, sfin_ref, state, *,
         sfin_ref[0, 0] = state[...].astype(sfin_ref.dtype)
 
 
-def rwkv6_wkv(r, k, v, w, u, *, chunk: int = 64, interpret: bool = True):
+def rwkv6_wkv(r, k, v, w, u, *, chunk: int = 64,
+              interpret: bool | None = None):
     """r,k,v,w [B,L,H,hd] (w in (0,1)); u [H,hd]
     -> (o [B,L,H,hd], state [B,H,hd,hd])."""
     B, L, H, hd = r.shape
@@ -88,6 +91,6 @@ def rwkv6_wkv(r, k, v, w, u, *, chunk: int = 64, interpret: bool = True):
             jax.ShapeDtypeStruct((B, H, hd, hd), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((hd, hd), jnp.float32)],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(r, k, v, w, u[None])
     return o, sfin

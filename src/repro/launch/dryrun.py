@@ -171,8 +171,6 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool,
 
     mem = compiled.memory_analysis()
     cost = compiled.cost_analysis() or {}
-    if isinstance(cost, (list, tuple)):   # older JAX: list of per-device dicts
-        cost = cost[0] if cost else {}
     hlo = compiled.as_text()
     hc = hlo_analyze(hlo)          # trip-count-aware flops/bytes/collectives
     mf = model_flops(cfg, shape)

@@ -10,27 +10,22 @@ touch jax device state (smoke tests see 1 device; only dryrun.py forces 512).
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
 
-def mesh_axis_kwargs(n_axes: int) -> dict:
-    """`axis_types=` kwargs for jax.make_mesh, across JAX versions.
-
-    jax.sharding.AxisType only exists in newer JAX; older versions default
-    every axis to Auto anyway, so omitting the kwarg is equivalent.
-    """
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return {}
-    return {"axis_types": (axis_type.Auto,) * n_axes}
+def make_mesh(shape, axes):
+    """``jax.make_mesh`` with every axis Auto: the sharding rules place
+    arrays through ``with_sharding_constraint``, not explicit axes. Takes
+    the first ``prod(shape)`` devices."""
+    return jax.make_mesh(shape, axes, (AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes, **mesh_axis_kwargs(len(axes)))
+    return make_mesh(shape, axes)
 
 
 def make_host_mesh(data: int = 1, model: int = 1):
     """Small mesh over however many (host) devices exist — tests/examples."""
-    return jax.make_mesh((data, model), ("data", "model"),
-                         **mesh_axis_kwargs(2))
+    return make_mesh((data, model), ("data", "model"))

@@ -17,9 +17,14 @@ from __future__ import annotations
 import argparse
 import threading
 import time
+from dataclasses import dataclass, replace
+from typing import Callable
 
 import jax
-import jax.numpy as jnp
+from jax.sharding import Mesh
+
+from repro.config import ModelConfig
+from repro.sharding import ShardingRules
 
 
 def start_stats_reporter(gsys, interval_s: float, *, out=print
@@ -46,7 +51,7 @@ def start_stats_reporter(gsys, interval_s: float, *, out=print
     return th, stop
 
 
-def main() -> None:
+def build_parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--reduced", action="store_true")
@@ -106,20 +111,17 @@ def main() -> None:
                     help="deterministic fault injection: "
                          "'SEED[;TENANT:SYSNO:ERRNO:RATE]...' with '*' "
                          "wildcards (e.g. '7;*:45:EAGAIN:0.01')")
-    args = ap.parse_args()
+    return ap
 
-    from repro.configs import get_config
+
+def make_server(args):
+    """The Genesys instance with the QoS policies, fault plan and
+    admission controller ``args`` ask for, and the UDP server bound on
+    ``--port`` -> ``(gsys, controller, srv)``."""
     from repro.core.genesys import (Genesys, GenesysConfig, StrictPriority,
-                                    TokenBucket, WeightedFair, format_summary)
-    from repro.launch.mesh import make_host_mesh
-    from repro.models.registry import get_api
+                                    TokenBucket, WeightedFair)
     from repro.serving.server import GenesysUdpServer
-    from repro.sharding import rules_for
-    from repro.train.steps import make_serve_step
 
-    cfg = get_config(args.arch)
-    if args.reduced:
-        cfg = cfg.reduced()
     gsys = Genesys(GenesysConfig(n_workers=2, sched_pollers=2,
                                  trace=args.trace_out is not None))
     if args.tenants:
@@ -148,7 +150,89 @@ def main() -> None:
         controller.install(gsys)
         print(f"admission control on: "
               f"{', '.join(c.name for c in classes)}", flush=True)
+    srv = GenesysUdpServer(gsys, port=args.port, use_ring=args.use_ring,
+                           use_tenants=args.tenants, admission=controller)
+    return gsys, controller, srv
 
+
+@dataclass
+class ServingModel:
+    cfg: ModelConfig          # params_dtype == compute_dtype
+    mesh: Mesh
+    rules: ShardingRules
+    params: dict
+    serve_step: Callable      # jitted one-token decode step
+
+
+def serving_config(cfg: ModelConfig) -> ModelConfig:
+    """Serving keeps its weights in the compute dtype (bf16): an f32 copy
+    of rwkv6-3b alone is 12.4 GB, and a step that casts f32 weights makes
+    another 5 GB of bf16 copies on every call."""
+    return replace(cfg, params_dtype=cfg.compute_dtype)
+
+
+def load_model(cfg: ModelConfig, seed: int = 0) -> ServingModel:
+    """Serving weights for ``cfg``, initialised directly in the compute
+    dtype, and its jitted decode step."""
+    from repro.launch.mesh import make_host_mesh
+    from repro.models.registry import init_params
+    from repro.sharding import rules_for
+    from repro.train.steps import make_serve_step
+
+    cfg = serving_config(cfg)
+    mesh = make_host_mesh()
+    rules = rules_for(cfg, mesh)
+    params = init_params(cfg, jax.random.PRNGKey(seed))
+    return ServingModel(cfg, mesh, rules, params,
+                        jax.jit(make_serve_step(cfg, rules)))
+
+
+def serve(args, gsys, srv, model: ServingModel, *, controller=None,
+          n_requests: int | None = None):
+    """Run the decode loop ``args`` selects (eager, ``--batch-decode``
+    buckets or the ``--continuous`` engine) on ``srv`` until ``--batches``
+    poll batches, or ``n_requests`` requests, are served."""
+    cfg = model.cfg
+    with model.mesh:
+        if args.continuous:
+            from repro.serving.engine import make_engine
+            engine = make_engine(
+                cfg, model.rules, model.params, n_slots=args.slots,
+                n_blocks=args.kv_blocks, block_size=args.block_size,
+                gsys=gsys, spill_path=args.spill)
+            engine.admission = controller
+            stats = srv.serve_model_continuous(
+                engine, reply_port=args.reply_port,
+                n_requests=n_requests, max_tokens=args.max_tokens,
+                per_request_tokens=args.per_request_tokens)
+            print(f"engine: occupancy={engine.stats.occupancy():.2f} "
+                  f"prefill_saved={engine.stats.prefill_steps_saved} "
+                  f"kv_hit_rate={engine.pool.stats.hit_rate():.2f} "
+                  f"kv_rss={engine.pool.rss_bytes()}")
+        else:
+            from repro.models.registry import get_api
+            cache = get_api(cfg).init_cache(cfg, 1, 256)
+            stats = srv.serve_model(
+                model.serve_step, model.params, cache,
+                n_batches=args.batches, n_requests=n_requests,
+                reply_port=args.reply_port, max_tokens=args.max_tokens,
+                batch_decode=args.batch_decode,
+                per_request_tokens=args.per_request_tokens)
+    return stats
+
+
+def main(argv=None) -> None:
+    args = build_parser().parse_args(argv)
+
+    from repro.configs import get_config
+    from repro.core.genesys import format_summary
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
+    cfg = get_config(args.arch)
+    if args.reduced:
+        cfg = cfg.reduced()
+    gsys, controller, srv = make_server(args)
     reporter = stop_stats = None
     if args.stats_interval > 0:
         reporter, stop_stats = start_stats_reporter(
@@ -165,36 +249,8 @@ def main() -> None:
                                         telemetry_fn=gsys.telemetry)
         print(f"metrics exposition on :{metrics_srv.port} "
               f"(/metrics, /telemetry)", flush=True)
-    mesh = make_host_mesh()
-    rules = rules_for(cfg, mesh)
-    api = get_api(cfg)
-    params, _ = api.init(jax.random.PRNGKey(0), cfg)
-    srv = GenesysUdpServer(gsys, port=args.port, use_ring=args.use_ring,
-                           use_tenants=args.tenants, admission=controller)
-    with mesh:
-        if args.continuous:
-            from repro.serving.engine import make_engine
-            engine = make_engine(
-                cfg, rules, params, n_slots=args.slots,
-                n_blocks=args.kv_blocks, block_size=args.block_size,
-                gsys=gsys, spill_path=args.spill)
-            engine.admission = controller
-            stats = srv.serve_model_continuous(
-                engine, reply_port=args.reply_port,
-                max_tokens=args.max_tokens,
-                per_request_tokens=args.per_request_tokens)
-            print(f"engine: occupancy={engine.stats.occupancy():.2f} "
-                  f"prefill_saved={engine.stats.prefill_steps_saved} "
-                  f"kv_hit_rate={engine.pool.stats.hit_rate():.2f} "
-                  f"kv_rss={engine.pool.rss_bytes()}")
-        else:
-            cache = api.init_cache(cfg, 1, 256)
-            serve = jax.jit(make_serve_step(cfg, rules))
-            stats = srv.serve_model(
-                serve, params, cache, n_batches=args.batches,
-                reply_port=args.reply_port, max_tokens=args.max_tokens,
-                batch_decode=args.batch_decode,
-                per_request_tokens=args.per_request_tokens)
+    model = load_model(cfg)
+    stats = serve(args, gsys, srv, model, controller=controller)
     print(f"requests={stats.requests} batches={stats.batches} "
           f"tokens={stats.tokens_out} wall={stats.wall_s:.2f}s "
           f"decode_dispatches={stats.decode_dispatches} "

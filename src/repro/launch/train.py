@@ -17,6 +17,35 @@ import jax
 import numpy as np
 
 
+def make_trainer(cfg, gsys, paths, mesh, *, batch: int, seq: int,
+                 lr: float = 3e-4, microbatches: int = 1, ckpt=None,
+                 ckpt_every: int = 50, seed: int = 0):
+    """A data-parallel :class:`~repro.train.loop.Trainer` over ``mesh``:
+    params and AdamW state are placed by their logical axes (replicated
+    over ``data``), each batch is split over ``data``, and the step
+    donates the state it replaces so it is never held twice. Returns
+    ``(trainer, loader)``; the caller closes the loader."""
+    from repro.config import TrainConfig
+    from repro.data.pipeline import GenesysDataLoader
+    from repro.models.registry import init_params
+    from repro.sharding import named_sharding, rules_for
+    from repro.train.loop import Trainer
+    from repro.train.steps import make_train_step
+
+    rules = rules_for(cfg, mesh)
+    params = init_params(cfg, jax.random.PRNGKey(seed), mesh=mesh,
+                         rules=rules)
+    ts, opt = make_train_step(cfg, rules, TrainConfig(
+        lr=lr, microbatches=microbatches))
+    loader = GenesysDataLoader(gsys, list(paths), batch=batch, seq=seq,
+                               seed=seed)
+    tr = Trainer(gsys, jax.jit(ts, donate_argnums=(0, 1)), params,
+                 jax.jit(opt.init)(params), loader, ckpt=ckpt,
+                 ckpt_every=ckpt_every,
+                 batch_sharding=named_sharding(mesh, rules, "batch", "seq"))
+    return tr, loader
+
+
 def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
@@ -34,16 +63,13 @@ def main() -> None:
     args = ap.parse_args()
 
     from repro.checkpoint.ckpt import CheckpointManager
-    from repro.config import TrainConfig
     from repro.configs import get_config
     from repro.core.genesys import Genesys, GenesysConfig
-    from repro.data.pipeline import GenesysDataLoader, write_token_shard
+    from repro.data.pipeline import write_token_shard
+    from repro.launch.compile_cache import enable_compile_cache
     from repro.launch.mesh import make_host_mesh
-    from repro.models.registry import get_api
-    from repro.sharding import rules_for
-    from repro.train.loop import Trainer
-    from repro.train.steps import make_train_step
 
+    enable_compile_cache()
     cfg = get_config(args.arch)
     if args.reduced:
         cfg = cfg.reduced()
@@ -58,19 +84,17 @@ def main() -> None:
         print(f"synthesized corpus at {data}")
 
     mesh = make_host_mesh(data=jax.device_count(), model=1)
-    rules = rules_for(cfg, mesh)
-    api = get_api(cfg)
-    params, _ = api.init(jax.random.PRNGKey(0), cfg)
-    ts, opt = make_train_step(cfg, rules, TrainConfig(
-        lr=args.lr, microbatches=args.microbatches))
-    loader = GenesysDataLoader(gsys, [data], batch=args.batch, seq=args.seq)
     ckpt = None
     if args.ckpt_dir:
         ckpt = CheckpointManager(gsys, args.ckpt_dir)
+    tr, loader = make_trainer(cfg, gsys, [data], mesh, batch=args.batch,
+                              seq=args.seq, lr=args.lr,
+                              microbatches=args.microbatches, ckpt=ckpt,
+                              ckpt_every=args.ckpt_every)
     with mesh:
-        tr = Trainer(gsys, jax.jit(ts), params, opt.init(params), loader,
-                     ckpt=ckpt, ckpt_every=args.ckpt_every)
-        if args.resume and ckpt is not None and tr.resume():
+        placed = jax.tree_util.tree_map(
+            lambda x: x.sharding, {"params": tr.params, "opt": tr.opt_state})
+        if args.resume and ckpt is not None and tr.resume(shardings=placed):
             print(f"resumed from step {tr.step}")
         st = tr.run(args.steps)
     print(f"steps={st.steps} loss[0]={st.losses[0]:.4f} "

@@ -9,6 +9,8 @@ from __future__ import annotations
 from dataclasses import dataclass
 from typing import Callable
 
+import jax
+
 from repro.config import ModelConfig, Family
 from repro.models import transformer, mamba2, rwkv6, encdec
 
@@ -39,3 +41,27 @@ _BY_FAMILY = {
 
 def get_api(cfg: ModelConfig) -> ModelApi:
     return _BY_FAMILY[cfg.family]
+
+
+def init_params(cfg: ModelConfig, rng, *, mesh=None, rules=None):
+    """``cfg``'s params in ``cfg.params_dtype``, made by ONE jitted init.
+
+    Under jit XLA writes each stacked leaf of ``stack_layers`` in place,
+    so the per-layer arrays never sit on the device beside the stacked
+    copy, and a bf16 config never holds an f32 copy. With ``mesh`` and
+    ``rules`` every leaf is placed by its logical axes (data-parallel
+    replicas, tensor-parallel shards); without them it lands on the
+    default device."""
+    api = get_api(cfg)
+    box = {}
+
+    def init(r):
+        params, box["axes"] = api.init(r, cfg)
+        return params
+
+    out_shardings = None
+    if mesh is not None:
+        from repro.sharding import tree_shardings
+        shapes = jax.eval_shape(init, rng)
+        out_shardings = tree_shardings(mesh, rules, box["axes"], shapes)
+    return jax.jit(init, out_shardings=out_shardings)(rng)

@@ -35,7 +35,8 @@ class LoopStats:
 class Trainer:
     def __init__(self, gsys: Genesys, train_step, params, opt_state, loader,
                  *, ckpt: CheckpointManager | None = None,
-                 ckpt_every: int = 50, step_deadline_s: float = 60.0):
+                 ckpt_every: int = 50, step_deadline_s: float = 60.0,
+                 batch_sharding=None):
         self.gsys = gsys
         self.train_step = train_step
         self.params = params
@@ -44,6 +45,9 @@ class Trainer:
         self.ckpt = ckpt
         self.ckpt_every = ckpt_every
         self.deadline = step_deadline_s
+        # where each batch goes: split over the data axis of a mesh for
+        # data parallelism; None puts it whole on the default device
+        self.batch_sharding = batch_sharding
         self.step = 0
         self.stats = LoopStats()
 
@@ -70,7 +74,7 @@ class Trainer:
             # stage through the host pool; release pages after device copy
             staging = self.gsys.pool.mmap(batch["tokens"].nbytes * 2)
             self.gsys.pool.touch(staging)
-            jbatch = jax.tree_util.tree_map(jax.numpy.asarray, batch)
+            jbatch = jax.device_put(batch, self.batch_sharding)
             self.gsys.call(Sys.MADVISE, staging, 0, MADV_DONTNEED,
                            blocking=False)    # §7.2: weak + non-blocking
 

@@ -1,7 +1,6 @@
 import sys
 from pathlib import Path
 
-import jax
 import pytest
 
 sys.path.insert(0, str(Path(__file__).parent))  # proptest helper
@@ -9,8 +8,8 @@ sys.path.insert(0, str(Path(__file__).parent))  # proptest helper
 
 @pytest.fixture(scope="session")
 def mesh11():
-    from repro.launch.mesh import mesh_axis_kwargs
-    return jax.make_mesh((1, 1), ("data", "model"), **mesh_axis_kwargs(2))
+    from repro.launch.mesh import make_host_mesh
+    return make_host_mesh()
 
 
 @pytest.fixture()

@@ -14,7 +14,7 @@ from jax.sharding import PartitionSpec as P
 
 from repro.checkpoint.ckpt import CheckpointManager
 from repro.data.pipeline import GenesysDataLoader, write_token_shard
-from repro.launch.mesh import mesh_axis_kwargs
+from repro.launch.mesh import make_host_mesh, make_mesh
 from repro.optim.compression import compress_tree, decompress_tree
 from repro.serving.server import CpuBaselineUdpServer, GenesysUdpServer
 from repro.sharding import (ShardingRules, apply_fsdp, fit_spec, kv_repeat,
@@ -89,7 +89,7 @@ def test_checkpoint_elastic_resharding(gsys, tmp_path):
     cm = CheckpointManager(gsys, str(tmp_path))
     t = _tree()
     cm.save(1, t)
-    mesh = jax.make_mesh((1,), ("model",), **mesh_axis_kwargs(1))
+    mesh = make_mesh((1,), ("model",))
     sh = jax.tree_util.tree_map(
         lambda _: jax.sharding.NamedSharding(mesh, P()), t)
     out = cm.restore(1, t, shardings=sh)
@@ -168,7 +168,7 @@ def test_bf16_compression_roundtrip():
 # ------------------------------------------------------------- sharding -----
 
 def test_fit_spec_drops_nondivisible():
-    mesh = jax.make_mesh((1, 1), ("data", "model"), **mesh_axis_kwargs(2))
+    mesh = make_host_mesh()
     # model axis size 1 -> kept as-is (harmless)
     assert fit_spec(P("model", None), (7, 3), mesh) == P("model", None)
 
@@ -183,7 +183,7 @@ def test_kv_repeat_rules():
 
 
 def test_apply_fsdp_picks_largest_free_dim():
-    mesh = jax.make_mesh((1, 1), ("data", "model"), **mesh_axis_kwargs(2))
+    mesh = make_host_mesh()
     spec = apply_fsdp(P(None, "model", None), ("embed", "heads", "head_dim"),
                       (4096, 32, 128), mesh, ("data",))
     assert spec == P(("data",), "model", None)

@@ -152,20 +152,15 @@ def test_compressed_crosspod_reduce_multidevice():
         "'--xla_force_host_platform_device_count=8'\n"
         "import jax, jax.numpy as jnp, numpy as np\n"
         "from jax.sharding import PartitionSpec as P\n"
-        "from repro.launch.mesh import mesh_axis_kwargs\n"
+        "from repro.launch.mesh import make_mesh\n"
         "from repro.optim.compression import compress_tree, decompress_tree\n"
-        "try:\n"
-        "    shard_map = jax.shard_map\n"
-        "except AttributeError:\n"
-        "    from jax.experimental.shard_map import shard_map\n"
-        "mesh = jax.make_mesh((2, 4), ('pod', 'data'),\n"
-        "    **mesh_axis_kwargs(2))\n"
+        "mesh = make_mesh((2, 4), ('pod', 'data'))\n"
         "def reduce_fn(g):\n"
         "    payload, _ = compress_tree({'g': g}, 'bf16')\n"
         "    summed = jax.lax.psum(payload['g'], ('pod', 'data'))\n"
         "    return decompress_tree({'g': summed}, 'bf16')['g']\n"
         "g = jnp.arange(8 * 64, dtype=jnp.float32).reshape(8, 64) / 100\n"
-        "out = jax.jit(shard_map(reduce_fn, mesh=mesh,\n"
+        "out = jax.jit(jax.shard_map(reduce_fn, mesh=mesh,\n"
         "    in_specs=P(('pod', 'data')), out_specs=P(('pod', 'data'))))(g)\n"
         "ref = jnp.broadcast_to(g.sum(0, keepdims=True), g.shape)\n"
         "err = float(jnp.max(jnp.abs(out - ref)))\n"

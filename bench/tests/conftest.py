@@ -1,0 +1,12 @@
+"""The benchmark's own tests: CPU only, small sizes.
+
+  python -m pytest bench/tests -q
+"""
+import os
+import sys
+from pathlib import Path
+
+BENCH = Path(__file__).resolve().parents[1]
+sys.path.insert(0, str(BENCH))
+sys.path.insert(0, str(BENCH.parent / "src"))
+os.environ.setdefault("JAX_PLATFORMS", "cpu")

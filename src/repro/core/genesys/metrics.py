@@ -352,29 +352,6 @@ class MetricsRegistry:
             d = self._hdelta_locked(i, span)
         return self._bucket_quantile(d, q)
 
-    def quantile_series(self, name: str, q: float = 0.99,
-                        **labels) -> list[float]:
-        """Per-window quantile series (oldest → newest): the quantile of
-        each window interval's own observations. When the ring has
-        wrapped, the oldest available snapshot only serves as a baseline
-        (its own interval's predecessor is gone)."""
-        key = (name,) + _labels_key(labels)
-        with self._lock:
-            i = self._hidx.get(key)
-            if i is None:
-                return []
-            avail = self._avail()
-            wrapped = self._wn > self.n_windows
-            snaps = [self._whb[(self._wn - j) % self.n_windows, i].astype(
-                np.float64) for j in range(avail, 0, -1)]
-        out: list[float] = []
-        prev = None if wrapped else np.zeros(N_BUCKETS)
-        for cur in snaps:
-            if prev is not None:
-                out.append(self._bucket_quantile(cur - prev, q))
-            prev = cur
-        return out
-
     # ---------------------------------------------------------- SLO burn ----
     def set_slo(self, name: str, slo_us: float, *, target: float = 0.999,
                 window: int = 12, **labels) -> None:

@@ -65,6 +65,35 @@ While a thread holds :meth:`Tracer.span`, every ring SUBMIT it records
 carries the span id in ``aux`` — so the Chrome exporter can nest the
 request's own syscalls (the reply SENDTO, KV spill/revival I/O) inside
 its request span on the pid-5 "request" track.
+
+A request's milestones in the continuous engine (``sysno`` =
+``REQ_SYSNO``, ``seq`` = span id):
+
+    ADMIT       the engine claimed a slot for it (aux = the request's
+                wire tag, the client's own request index, or -1 without
+                one: joined with the client's send time, both on
+                CLOCK_MONOTONIC, it gives the request's wait for a slot)
+    FIRST_TOKEN the engine appended its first generated token (ts = when
+                the host had it, aux = the engine step's index)
+
+The serving loop (``serve_model_continuous``, channel ``loop``): every
+iteration is recorded by a :class:`LoopRecorder` as one ``LOOP`` event
+per phase it ran, in one staged block:
+
+    LOOP        sysno = phase code (``LOOP_PHASES``), seq = iteration
+                index, ts = phase start, aux = phase duration (ns); the
+                phases of one iteration tile it, each starting where the
+                one before ended
+
+Pairing with a device trace. Event timestamps are ``perf_counter_ns``;
+a JAX profile counts from when its session began, so no fixed offset
+joins the two clocks. While tracing is on, each iteration also runs
+under ``jax.profiler.TraceAnnotation("serve.iter", it=<index>)`` and
+each phase under ``TraceAnnotation("serve.<phase>")``. The offset from
+``perf_counter_ns`` to the profile's clock is the median, over the
+iterations found in both, of (``serve.iter`` start - the iteration's
+first ``LOOP`` ts); both stamps are taken back to back, so it varies
+by microseconds, not milliseconds.
 """
 from __future__ import annotations
 
@@ -99,14 +128,29 @@ EV_REJECT = 10
 EV_REQ_BEGIN = 11
 EV_REQ_END = 12
 EV_STEP = 13
+EV_LOOP = 14
+EV_ADMIT = 15
+EV_FIRST_TOKEN = 16
 
 EV_NAMES = {
     EV_SUBMIT: "SUBMIT", EV_SQ_POP: "SQ_POP", EV_FUSE_MERGE: "FUSE_MERGE",
     EV_DISPATCH: "DISPATCH", EV_COMPLETE: "COMPLETE", EV_REAP: "REAP",
     EV_IRQ: "IRQ", EV_FALLBACK: "FALLBACK", EV_THROTTLE: "THROTTLE",
     EV_REJECT: "REJECT", EV_REQ_BEGIN: "REQ_BEGIN", EV_REQ_END: "REQ_END",
-    EV_STEP: "STEP",
+    EV_STEP: "STEP", EV_LOOP: "LOOP", EV_ADMIT: "ADMIT",
+    EV_FIRST_TOKEN: "FIRST_TOKEN",
 }
+
+# serving-loop phases, in the order one iteration runs them: the code
+# (an EV_LOOP event's sysno) is the index. poll: receive and parse;
+# admit: queued requests into free slots; dispatch: the uploads and the
+# jitted step call; sync: the wait for its tokens; advance: the per-slot
+# bookkeeping; retire: freeing finished slots (their MADVISE waits);
+# reply: the answers' sends
+LOOP_PHASES = ("poll", "admit", "dispatch", "sync", "advance", "retire",
+               "reply")
+(LOOP_POLL, LOOP_ADMIT, LOOP_DISPATCH, LOOP_SYNC, LOOP_ADVANCE,
+ LOOP_RETIRE, LOOP_REPLY) = range(len(LOOP_PHASES))
 
 # the sysno request-span events carry (a request is not one syscall);
 # latency_histograms names it "REQUEST" so the serving channel's
@@ -227,8 +271,9 @@ class EventRing:
         self.buf = np.zeros(self.capacity, dtype=EVENT_DTYPE)
         self._tail = 0           # monotonic append count (incl. staged)
         self._flushed = 0        # events materialized into buf
-        # staged blocks: (ts, ev, tenant, sysno, seq, aux, n); sysno /
-        # seq / aux are scalars, lists, or arrays (converted at flush)
+        # staged blocks: (ts, ev, tenant, sysno, seq, aux, n); ts /
+        # sysno / seq / aux are scalars, lists, or arrays (converted at
+        # flush)
         self._pending: deque = deque()
         self._staged = 0
         self._lock = threading.Lock()
@@ -256,7 +301,7 @@ class EventRing:
                      ts: int | None = None, own: bool = False) -> None:
         """Record ``len(seqs)`` events sharing one timestamp (bundle
         grain — exactly the granularity the ring machinery itself works
-        at). ``sysnos``/``aux`` may be scalars or per-event columns.
+        at). ``sysnos``/``aux``/``ts`` may be scalars or per-event columns.
         Columns may be lists or arrays; lists are always staged by
         reference and arrays too when ``own=True`` — either way the
         caller must not mutate them afterwards (every genesys site
@@ -273,6 +318,8 @@ class EventRing:
             return
         if ts is None:
             ts = time.perf_counter_ns()
+        elif isinstance(ts, (int, np.integer)):
+            ts = int(ts)
         if isinstance(sysnos, (int, np.integer)):
             sysnos = int(sysnos)
         elif isinstance(sysnos, np.ndarray) and not own:
@@ -311,7 +358,7 @@ class EventRing:
                 [_col_part(v, n, dt) for v, n in zip(vals, lens)])
 
         cols = {
-            "ts": np.repeat(np.array([b[0] for b in blocks], np.int64), lens),
+            "ts": col(0, np.int64),
             "ev": np.repeat(np.array([b[1] for b in blocks], np.int16), lens),
             "tenant": np.repeat(
                 np.array([b[2] for b in blocks], np.int16), lens),
@@ -376,6 +423,58 @@ class TraceChannel:
     def span_aux(self) -> int:
         """The calling thread's current request-span id (0 = none)."""
         return self.tracer.current_span()
+
+
+class LoopRecorder:
+    """Phase clock of one serving loop, made only while tracing is on.
+
+    :meth:`begin` opens an iteration in ``poll``, :meth:`phase`
+    ends the running phase and starts the next at one timestamp, and
+    :meth:`end` closes the iteration and records it as ONE staged block
+    of ``EV_LOOP`` events (one lock round per iteration, none per
+    phase). Each iteration also runs under a ``serve.iter`` profiler
+    annotation carrying its index, and each phase under
+    ``serve.<phase>``, so a device trace names the host's work in every
+    gap (see the module docstring for pairing the clocks)."""
+
+    __slots__ = ("ch", "it", "_ann", "_codes", "_ts", "_outer", "_inner")
+
+    def __init__(self, channel: TraceChannel):
+        from jax import profiler
+        self.ch = channel
+        self.it = 0
+        self._ann = profiler.TraceAnnotation
+        self._codes: list[int] = []
+        self._ts: list[int] = []
+        self._outer = self._inner = None
+
+    def begin(self) -> None:
+        self._outer = self._ann("serve.iter", it=self.it)
+        self._outer.__enter__()
+        self._ts = [time.perf_counter_ns()]
+        self._codes = [LOOP_POLL]
+        self._inner = self._ann("serve." + LOOP_PHASES[LOOP_POLL])
+        self._inner.__enter__()
+
+    def phase(self, code: int) -> None:
+        t = time.perf_counter_ns()
+        self._inner.__exit__(None, None, None)
+        self._inner = self._ann("serve." + LOOP_PHASES[code])
+        self._inner.__enter__()
+        self._ts.append(t)
+        self._codes.append(code)
+
+    def end(self) -> None:
+        t = time.perf_counter_ns()
+        self._inner.__exit__(None, None, None)
+        self._outer.__exit__(None, None, None)
+        ts = self._ts
+        ts.append(t)
+        n = len(self._codes)
+        self.ch.rec_block(EV_LOOP, self._codes, [self.it] * n,
+                          aux=[b - a for a, b in zip(ts, ts[1:])],
+                          ts=ts[:-1], own=True)
+        self.it += 1
 
 
 class Tracer:
@@ -476,9 +575,11 @@ class Tracer:
         spans per channel, REAP instants), pid 5 "request" (one track
         per serving request span id: the request's wall-time span, its
         engine decode-step spans, and every span-attributed syscall
-        nested inside). Spans beyond ``max_spans`` are counted, not
-        silently elided: ``trace["metadata"]["dropped_spans"]`` reports
-        the loss. Returns the trace dict."""
+        nested inside), pid 6 "loop" (the serving loop's phases, one
+        span each, on one track). Spans beyond ``max_spans`` are
+        counted, not silently elided:
+        ``trace["metadata"]["dropped_spans"]`` reports the loss. Returns
+        the trace dict."""
         evs = self.events.snapshot()
         ch_names = self.channel_names()
         th_names = self.thread_names()
@@ -493,7 +594,7 @@ class Tracer:
                 out.append(rec)
 
         for pid, pname in ((1, "ring"), (2, "poller"), (3, "worker"),
-                           (4, "tenant"), (5, "request")):
+                           (4, "tenant"), (5, "request"), (6, "loop")):
             out.append({"ph": "M", "pid": pid, "tid": 0,
                         "name": "process_name", "args": {"name": pname}})
         for pid in (1, 4):
@@ -564,6 +665,11 @@ class Tracer:
                      "dur": max(0.0, us(b["ts"]) - us(a["ts"])),
                      "name": f"sys:{sysname(a)}",
                      "args": {"seq": int(a["seq"])}})
+            for r in evs[evs["ev"] == EV_LOOP]:
+                put({"ph": "X", "pid": 6, "tid": 0, "ts": us(r["ts"]),
+                     "dur": int(r["aux"]) / 1e3,
+                     "name": LOOP_PHASES[int(r["sysno"])],
+                     "args": {"iter": int(r["seq"])}})
             for seq in np.unique(
                     evs[evs["ev"] == EV_REQ_BEGIN]["seq"])[:256]:
                 out.append({"ph": "M", "pid": 5, "tid": int(seq),

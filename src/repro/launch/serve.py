@@ -122,8 +122,12 @@ def make_server(args):
                                     TokenBucket, WeightedFair)
     from repro.serving.server import GenesysUdpServer
 
+    # a continuous server records about 1.5k trace events a second (one
+    # STEP per busy slot per step, the loop's phases, each call's
+    # lifecycle): 2^20 entries (32 MiB) hold ten minutes without wrapping
     gsys = Genesys(GenesysConfig(n_workers=2, sched_pollers=2,
-                                 trace=args.trace_out is not None))
+                                 trace=args.trace_out is not None,
+                                 trace_capacity=1 << 20))
     if args.tenants:
         gsys.use_policies(TokenBucket(), StrictPriority(), WeightedFair())
     if args.fault_plan:

@@ -33,7 +33,10 @@ from dataclasses import dataclass, field
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core.genesys.trace import EV_STEP, Counters
+from repro.core.genesys.trace import (
+    EV_ADMIT, EV_FIRST_TOKEN, EV_STEP, LOOP_ADVANCE, LOOP_DISPATCH,
+    LOOP_RETIRE, LOOP_SYNC, REQ_SYSNO, Counters,
+)
 from repro.serving.pagedkv import NULL_BLOCK, PagedKVPool, PoolExhausted
 
 
@@ -144,10 +147,13 @@ class ContinuousBatchEngine:
 
     # ----------------------------------------------------------- admission --
     def admit(self, prompt, n_tokens: int, meta=None,
-              span: int = 0) -> bool:
+              span: int = 0, tag: int | None = None) -> bool:
         """Claim a slot for a request mid-decode. Returns False (admitting
         nothing) when no slot or not enough arena blocks are available —
         the caller keeps the request queued and retries after retirements.
+        With tracing on, success records ``EV_ADMIT`` for ``span``
+        carrying the request's wire ``tag`` (-1 for a request without
+        one).
         """
         prompt = np.asarray(prompt, np.int32).reshape(-1)
         n, budget = len(prompt), max(1, int(n_tokens))
@@ -189,6 +195,10 @@ class ContinuousBatchEngine:
         self._cl[slot] = r
         self._cur[slot, 0] = prompt[r]
         self.counters.add(admitted=1, prefill_steps_saved=r)
+        ch = self.trace
+        if ch is not None:
+            ch.rec(EV_ADMIT, REQ_SYSNO, span,
+                   aux=-1 if tag is None else tag)
         return True
 
     def _retire(self, slot: int, st: _Slot) -> None:
@@ -207,35 +217,33 @@ class ContinuousBatchEngine:
         self.counters.add(retired=1)
 
     # ---------------------------------------------------------- decoding ----
-    def step(self) -> list[tuple[object, list[int]]]:
+    def step(self, loop=None) -> list[tuple[object, list[int]]]:
         """One fixed-shape decode dispatch for every slot; advances each
         active slot through prefill or generation and retires finished
         requests. Returns the ``(meta, generated_tokens)`` pairs that
-        completed on this step."""
+        completed on this step. ``loop`` is the serving loop's
+        :class:`~repro.core.genesys.trace.LoopRecorder` (tracing on):
+        the step marks its dispatch, sync, advance and retire phases."""
         active = [(i, s) for i, s in enumerate(self._slots) if s is not None]
         if not active:
             return []
+        if loop is not None:
+            loop.phase(LOOP_DISPATCH)
         t0 = time.perf_counter_ns()
         nxt, self.arenas = self.serve_step(
             self.params, self.arenas, jnp.asarray(self._bt),
             jnp.asarray(self._cur), jnp.asarray(self._cl))
+        if loop is not None:
+            loop.phase(LOOP_SYNC)
         nxt = np.asarray(nxt)
         dur = time.perf_counter_ns() - t0
+        if loop is not None:
+            loop.phase(LOOP_ADVANCE)
         self.counters.add(steps=1, step_slots=len(active))
         if self.serve_stats is not None:
             self.serve_stats.add(decode_dispatches=1,
                                  decode_steps=len(active))
-        ch = self.trace
-        if ch is not None:
-            # one self-contained EV_STEP per active request span: ts is
-            # the dispatch start, aux the duration (ns) — no begin/end
-            # pair to join, since a span repeats its seq across steps
-            spans = [s.span for _, s in active if s.span]
-            if spans:
-                ch.rec_block(EV_STEP, self._step_idx, spans, aux=dur,
-                             ts=t0, own=True)
-        self._step_idx += 1
-        finished = []
+        done = []
         prefills = 0
         for i, st in active:
             st.cache_len += 1               # the fed token's KV landed
@@ -247,14 +255,34 @@ class ContinuousBatchEngine:
             else:
                 st.gen.append(int(nxt[i]))
                 if len(st.gen) >= st.budget:
-                    finished.append((st.meta, st.gen))
-                    self._retire(i, st)
+                    done.append((i, st))
                     continue
                 self._cur[i, 0] = st.gen[-1]
             self._cl[i] = st.cache_len
         if prefills:
             self.counters.add(prefill_steps=prefills)
-        return finished
+        ch = self.trace
+        if ch is not None:
+            # one self-contained EV_STEP per active request span: ts is
+            # the dispatch start, aux the duration (ns) — no begin/end
+            # pair to join, since a span repeats its seq across steps
+            spans = [s.span for _, s in active if s.span]
+            if spans:
+                ch.rec_block(EV_STEP, self._step_idx, spans, aux=dur,
+                             ts=t0, own=True)
+            # a slot holds one generated token only on the step that
+            # produced its first
+            firsts = [s.span for _, s in active
+                      if s.span and len(s.gen) == 1]
+            if firsts:
+                ch.rec_block(EV_FIRST_TOKEN, REQ_SYSNO, firsts,
+                             aux=self._step_idx, ts=t0 + dur, own=True)
+        self._step_idx += 1
+        if loop is not None:
+            loop.phase(LOOP_RETIRE)
+        for i, st in done:
+            self._retire(i, st)
+        return [(st.meta, st.gen) for _, st in done]
 
     def drain(self) -> list[tuple[object, list[int]]]:
         """Run steps until every active request has retired."""

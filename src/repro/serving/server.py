@@ -41,7 +41,8 @@ import numpy as np
 
 from repro.core.genesys import Genesys, Sys
 from repro.core.genesys.trace import (
-    EV_REQ_BEGIN, EV_REQ_END, REQ_SYSNO, Counters, jsonable, summary_dict,
+    EV_REQ_BEGIN, EV_REQ_END, LOOP_ADMIT, LOOP_REPLY, REQ_SYSNO, Counters,
+    LoopRecorder, jsonable, summary_dict,
 )
 
 # STATS request op: a datagram ``GSTATS1\0 + uint32 reply_port (LE)``
@@ -450,12 +451,19 @@ class GenesysUdpServer:
         records one EV_STEP per span per decode dispatch, and admission/
         retirement/reply syscalls submitted under ``Tracer.span`` carry
         the id in their SUBMIT aux — ``export_chrome_trace`` renders one
-        pid-5 track per request nesting its steps and syscalls.
+        pid-5 track per request nesting its steps and syscalls. The
+        engine records each request's ADMIT (with its wire tag) and
+        FIRST_TOKEN, and a :class:`LoopRecorder` records every
+        iteration's phases (poll, admit, the engine's dispatch, sync,
+        advance and retire, reply) as EV_LOOP events and profiler
+        annotations.
         """
         t0 = time.monotonic()
         engine.serve_stats = self.counters
         tracer = self.gsys.tracer
         ch = tracer.channel("requests") if tracer is not None else None
+        lp = (LoopRecorder(tracer.channel("loop")) if tracer is not None
+              else None)
         engine.trace = ch
         adm = self.admission
         # queue entries: (toks, budget, tag, span, t_parse_ns, client)
@@ -463,9 +471,11 @@ class GenesysUdpServer:
         idle = 0
         replied = 0
         while True:
-            busy = engine.n_active > 0 or bool(queue)
             if n_requests is not None and replied >= n_requests:
                 break
+            if lp is not None:
+                lp.begin()
+            busy = engine.n_active > 0 or bool(queue)
             if busy and len(queue) >= engine.free_slots:
                 reqs = []           # nothing to admit into: don't block
                 self.counters.add(poll_skips=1)
@@ -504,9 +514,13 @@ class GenesysUdpServer:
                     queue.append((toks, budget, tag, span, now_ns, client))
             elif not busy:
                 idle += 1
+                if lp is not None:
+                    lp.end()
                 if n_requests is None or idle >= max_idle_polls:
                     break               # traffic died before the target
                 continue
+            if lp is not None:
+                lp.phase(LOOP_ADMIT)
             # admit as many queued requests as slots/blocks allow — the
             # rest stay queued and retry after the next retirements
             while queue:
@@ -517,9 +531,10 @@ class GenesysUdpServer:
                     # belong to this request's span
                     with tracer.span(span):
                         ok = engine.admit(toks, budget, meta=meta,
-                                          span=span)
+                                          span=span, tag=tag)
                 else:
-                    ok = engine.admit(toks, budget, meta=meta, span=span)
+                    ok = engine.admit(toks, budget, meta=meta, span=span,
+                                      tag=tag)
                 if not ok:
                     break
                 queue.pop(0)
@@ -528,7 +543,10 @@ class GenesysUdpServer:
                 setattr(s, "queue_depth", depth),
                 setattr(s, "queue_depth_peak",
                         max(s.queue_depth_peak, depth))))
-            for meta, gen in engine.step():
+            finished = engine.step(lp)
+            if lp is not None:
+                lp.phase(LOOP_REPLY)
+            for meta, gen in finished:
                 tag, span, tns, client = meta
                 if span:
                     with tracer.span(span):
@@ -544,6 +562,8 @@ class GenesysUdpServer:
                     adm.observe(client, wall_us)
                 self.counters.add(tokens_out=len(gen))
                 replied += 1
+            if lp is not None:
+                lp.end()
         self.gsys.drain()
         self._release_pending()
         wall = time.monotonic() - t0

@@ -11,9 +11,11 @@ import numpy as np
 import pytest
 
 from repro.core.genesys import Genesys, GenesysConfig, Sys
-from repro.core.genesys.trace import (EV_COMPLETE, EV_DISPATCH,
-                                      EV_FUSE_MERGE, EV_NAMES, EV_REAP,
-                                      EV_SQ_POP, EV_SUBMIT, Counters,
+from repro.core.genesys.trace import (EV_ADMIT, EV_COMPLETE, EV_DISPATCH,
+                                      EV_FIRST_TOKEN, EV_FUSE_MERGE,
+                                      EV_LOOP, EV_NAMES, EV_REAP,
+                                      EV_REQ_BEGIN, EV_REQ_END, EV_SQ_POP,
+                                      EV_SUBMIT, LOOP_PHASES, Counters,
                                       EventRing, Tracer, bucket_of,
                                       format_summary, latency_histograms,
                                       summary_dict)
@@ -95,6 +97,17 @@ def test_event_ring_order_and_mixed_columns():
     assert s["tenant"].tolist() == [0, 1, 1, 0, 0, 2]
     assert s["aux"].tolist() == [3, 9, 9, 0, 0, 0]
     assert r.total == 6 and r.dropped == 0
+
+
+def test_event_ring_per_event_timestamps():
+    r = EventRing(64)
+    r.append_block(EV_LOOP, 0, [0, 1, 2], [7, 7, 7], aux=[5, 6, 7],
+                   ts=[100, 105, 111], own=True)
+    r.append_block(EV_SUBMIT, 0, 1, [8, 9], ts=np.int64(200))
+    s = r.snapshot()
+    assert s["ts"].tolist() == [100, 105, 111, 200, 200]
+    assert s["sysno"].tolist() == [0, 1, 2, 1, 1]
+    assert s["aux"].tolist() == [5, 6, 7, 0, 0]
 
 
 def test_event_ring_wraparound_keeps_newest():
@@ -384,3 +397,151 @@ def test_server_stats_op_returns_telemetry_json():
     finally:
         srv.close()
         g.shutdown()
+
+
+# ------------------------------------------------ serving-loop phases ----
+
+class _CountingAnnotation:
+    """Stands in for jax.profiler.TraceAnnotation: counts constructions
+    and stamps ``serve.iter`` entries and exits on perf_counter_ns."""
+    made = 0
+    iters: list = []
+
+    def __init__(self, name, **kw):
+        type(self).made += 1
+        self.name = name
+
+    def __enter__(self):
+        if self.name == "serve.iter":
+            self.t0 = time.perf_counter_ns()
+        return self
+
+    def __exit__(self, *exc):
+        if self.name == "serve.iter":
+            type(self).iters.append((self.t0, time.perf_counter_ns()))
+
+
+def _serve_tiny(trace: bool, monkeypatch, tmp_path=None):
+    """Three requests through serve_model_continuous with a stub step
+    (2 ms a step) -> (replies by tag, events or None, chrome trace)."""
+    import jax
+    import jax.numpy as jnp
+
+    from repro.serving.engine import ContinuousBatchEngine
+    from repro.serving.pagedkv import PagedKVPool
+    from repro.serving.server import GenesysUdpServer
+    monkeypatch.setattr(_CountingAnnotation, "made", 0)
+    monkeypatch.setattr(_CountingAnnotation, "iters", [])
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", _CountingAnnotation)
+
+    def step(p, a, bt, cur, cl):
+        time.sleep(0.002)
+        return cur[:, 0] * 2 + 1, a
+
+    g = Genesys(GenesysConfig(n_workers=2, trace=trace))
+    try:
+        NB, BS = 8, 4
+        arenas = {"k": jnp.zeros((1, NB, BS, 1, 1)),
+                  "v": jnp.zeros((1, NB, BS, 1, 1))}
+        eng = ContinuousBatchEngine(step, {}, arenas, PagedKVPool(NB, BS),
+                                    n_slots=2, max_blocks_per_seq=4)
+        eng.pool.bind_genesys(g, block_bytes=64)   # MADVISE on retire
+        srv = GenesysUdpServer(g, port=0, max_batch=4, payload=256,
+                               batch_window_s=0.02, use_ring=True)
+        g.table._sockets[srv.fd].settimeout(0.05)  # cheap idle polls
+        port = g.table._sockets[srv.fd].getsockname()[1]
+        client = socket.socket(socket.AF_INET, socket.SOCK_DGRAM)
+        client.bind(("127.0.0.1", 0))
+        client.settimeout(10)
+        th = threading.Thread(
+            target=lambda: srv.serve_model_continuous(
+                eng, reply_port=client.getsockname()[1], n_requests=3,
+                max_idle_polls=50),
+            daemon=True)
+        th.start()
+        time.sleep(0.05)
+        # [budget, tag, prompt...]; three requests for two slots, so one
+        # waits in the queue for a retirement
+        for req in ([3, 900, 5], [2, 901, 7, 8], [4, 902, 1, 2, 3]):
+            client.sendto(np.asarray(req, np.int32).tobytes(),
+                          ("127.0.0.1", port))
+        replies = {}
+        for _ in range(3):
+            words = np.frombuffer(client.recvfrom(4096)[0], np.int32)
+            replies[int(words[0])] = words[1:].tolist()
+        th.join(20)
+        assert not th.is_alive()
+        client.close()
+        srv.close()
+        evs = g.tracer.events.snapshot() if trace else None
+        chrome = (g.export_chrome_trace(str(tmp_path / "trace.json"))
+                  if trace and tmp_path is not None else None)
+    finally:
+        g.shutdown()
+    assert sorted(replies) == [900, 901, 902]
+    return replies, evs, chrome
+
+
+def test_loop_tracing_off_records_nothing_and_annotates_nothing(monkeypatch):
+    replies, evs, _ = _serve_tiny(False, monkeypatch)
+    assert evs is None                        # no tracer, so no events
+    assert _CountingAnnotation.made == 0
+    assert [len(replies[t]) for t in (900, 901, 902)] == [3, 2, 4]
+
+
+def test_loop_tracing_on_annotates_every_phase(monkeypatch):
+    _, evs, _ = _serve_tiny(True, monkeypatch)
+    loop = evs[evs["ev"] == EV_LOOP]
+    n_iters = len(np.unique(loop["seq"]))
+    # one serve.iter and one serve.<phase> annotation per recorded phase
+    assert _CountingAnnotation.made == n_iters + len(loop)
+    assert len(_CountingAnnotation.iters) == n_iters
+
+
+def test_request_milestones_in_order(monkeypatch):
+    _, evs, _ = _serve_tiny(True, monkeypatch)
+
+    def by_span(ev):
+        e = evs[evs["ev"] == ev]
+        return dict(zip(e["seq"].tolist(), e["ts"].tolist()))
+
+    begin, admit, first, end = (by_span(ev) for ev in (
+        EV_REQ_BEGIN, EV_ADMIT, EV_FIRST_TOKEN, EV_REQ_END))
+    assert len(end) == 3
+    for span in end:
+        assert begin[span] <= admit[span] <= first[span] <= end[span]
+    adm = evs[evs["ev"] == EV_ADMIT]
+    assert sorted(adm["aux"].tolist()) == [900, 901, 902]   # wire tags
+
+
+def test_loop_phases_tile_each_iteration(monkeypatch):
+    _, evs, _ = _serve_tiny(True, monkeypatch)
+    loop = evs[evs["ev"] == EV_LOOP]
+    covered = 0
+    stepped = 0
+    for it in np.unique(loop["seq"]):
+        ph = loop[loop["seq"] == it]
+        ph = ph[np.argsort(ph["ts"], kind="stable")]
+        codes = ph["sysno"].tolist()
+        assert codes == sorted(codes) and len(set(codes)) == len(codes)
+        assert codes[0] == LOOP_PHASES.index("poll")
+        # no overlap and no hole: each phase starts where the last ended
+        assert (ph["ts"][1:] == ph["ts"][:-1] + ph["aux"][:-1]).all()
+        covered += int(ph["aux"].sum())
+        stepped += LOOP_PHASES.index("dispatch") in codes
+    assert stepped >= 4                        # budgets 3, 2, 4 on 2 slots
+    # the serve.iter annotations bracket each iteration on their own
+    # clock reading: the phases cover nearly all of that wall time
+    wall = sum(b - a for a, b in _CountingAnnotation.iters)
+    assert covered >= 0.95 * wall
+
+
+def test_chrome_trace_has_the_loop_track(monkeypatch, tmp_path):
+    _, evs, chrome = _serve_tiny(True, monkeypatch, tmp_path)
+    evts = chrome["traceEvents"]
+    assert {"ph": "M", "pid": 6, "tid": 0, "name": "process_name",
+            "args": {"name": "loop"}} in evts
+    spans = [e for e in evts if e["ph"] == "X" and e["pid"] == 6]
+    assert len(spans) == int((evs["ev"] == EV_LOOP).sum())
+    assert {e["name"] for e in spans} <= set(LOOP_PHASES)
+    assert {"poll", "dispatch", "sync", "reply"} <= {e["name"] for e in spans}

@@ -146,6 +146,10 @@ def _stub_step(params, arenas, bt, cur, cl):
     return cur[:, 0] * 2 + 1, arenas
 
 
+def _stub_chunk(params, arenas, row, tokens, start, n_valid):
+    return arenas
+
+
 def _check_nesting(trace: dict) -> tuple[int, int, int]:
     """(request spans, spans nesting a step, spans nesting a syscall)."""
     evs = [e for e in trace["traceEvents"] if e.get("pid") == 5
@@ -183,8 +187,9 @@ def _measure_serving(quick: bool, prom_out: str | None,
         arenas = {"k": jnp.zeros((1, NB, BS, 1, 1)),
                   "v": jnp.zeros((1, NB, BS, 1, 1))}
         eng = ContinuousBatchEngine(_stub_step, {}, arenas,
-                                    PagedKVPool(NB, BS), n_slots=N_SLOTS,
-                                    max_blocks_per_seq=8)
+                                    PagedKVPool(NB, BS),
+                                    prefill_chunk=_stub_chunk,
+                                    n_slots=N_SLOTS, max_blocks_per_seq=8)
         eng.pool.bind_genesys(g, block_bytes=64)   # MADVISE on retire
         srv = GenesysUdpServer(g, port=0, max_batch=N_SLOTS, payload=512,
                                batch_window_s=0.005, use_ring=True)

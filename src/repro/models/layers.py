@@ -184,6 +184,36 @@ def paged_attention_xla(q, k_pages, v_pages, block_tables, lens, *,
     return decode_attention_xla(q, kd, vd, lens, scale=scale)
 
 
+def paged_chunk_attention_xla(q, k_pages, v_pages, block_tables, positions,
+                              *, scale=None):
+    """Causal attention of S queries per row against the genesys.pagedkv
+    block arena: the query at ``positions[b, i]`` attends every position
+    of its row up to and including its own. The masked float32 softmax of
+    :func:`decode_attention_xla`, over the same gathered pages as
+    :func:`paged_attention_xla`. q [B,S,H,hd]; k_pages/v_pages
+    [NB,BS,KV,hd]; block_tables [B,MB]; positions [B,S]."""
+    B, S, H, hd = q.shape
+    NB, BS, KV, _ = k_pages.shape
+    MB = block_tables.shape[1]
+    G = H // KV
+    scale = scale if scale is not None else hd ** -0.5
+    kd = k_pages[block_tables].reshape(B, MB * BS, KV, hd)
+    vd = v_pages[block_tables].reshape(B, MB * BS, KV, hd)
+    if k_pages.dtype == jnp.int8:
+        kd = kd.astype(q.dtype) / QSCALE
+        vd = vd.astype(q.dtype) / QSCALE
+    qr = q.reshape(B, S, KV, G, hd)
+    s = jnp.einsum("bqkgh,bskh->bkgqs", qr, kd,
+                   preferred_element_type=jnp.float32) * scale
+    pos = jnp.arange(MB * BS, dtype=jnp.int32)
+    mask = pos[None, None, :] <= positions[:, :, None]      # [B,S,MB*BS]
+    s = jnp.where(mask[:, None, None], s, -jnp.inf)
+    p = jax.nn.softmax(s, axis=-1)
+    o = jnp.einsum("bkgqs,bskh->bqkgh", p.astype(vd.dtype), vd,
+                   preferred_element_type=jnp.float32)
+    return o.reshape(B, S, H, hd).astype(q.dtype)
+
+
 # ------------------------------------------------------------ attention -----
 
 def init_attention(pb, cfg, *, rope_scaled: bool = True, prefix: str = "attn"):
@@ -201,13 +231,14 @@ def init_attention(pb, cfg, *, rope_scaled: bool = True, prefix: str = "attn"):
 
 def attention(p, cfg, rules, x, *, positions, causal=True, kv_x=None,
               cache=None, cache_len=None, use_rope=True,
-              carried_cache=None, paged_cache=None):
+              carried_cache=None, paged_cache=None, n_valid=None):
     """GQA attention. cache: dict(k,v) [B,S,KV,hd] for decode; kv_x for
     cross-attention (enc-dec); carried_cache: (kc, vc, layer_idx) stacked
     [L,B,S,KV,hd] buffers updated in place; paged_cache:
     (k_pages, v_pages, block_tables, layer_idx) stacked [L,NB,BS,KV,hd]
     genesys.pagedkv arenas addressed per row through block_tables [B,MB]
-    with a per-row cache_len. Returns (out, new_cache)."""
+    with a per-row cache_len; for S > 1, n_valid [B] counts each row's
+    real tokens (the rest are padding). Returns (out, new_cache)."""
     dt = x.dtype
     kv_src = x if kv_x is None else kv_x
     q = jnp.einsum("bsd,dhk->bshk", x, p["wq"].astype(dt))
@@ -235,14 +266,18 @@ def attention(p, cfg, rules, x, *, positions, causal=True, kv_x=None,
 
     new_cache = None
     if paged_cache is not None and kv_x is None:
-        # decode against the genesys.pagedkv block arena [L,NB,BS,KV,hd]:
-        # WRITE the new token's K/V at its block-table slot (the
-        # update_kv_buffer scatter at block_tables[b, cl//BS]*BS + cl%BS),
-        # then attend through the block table with per-row lens =
-        # cache_len + 1. Inactive batch rows carry all-null block tables
-        # and cache_len 0, so their writes land in the pool's null block
-        # and their outputs are garbage nobody reads (slot-masked
-        # continuous batching, serving/engine.py).
+        # the genesys.pagedkv block arena [L,NB,BS,KV,hd], chosen by the
+        # static length S. S == 1 (the decode step): WRITE the new token's
+        # K/V at its block-table slot (the update_kv_buffer scatter at
+        # block_tables[b, cl//BS]*BS + cl%BS), then attend through the
+        # block table with per-row lens = cache_len + 1. Inactive batch
+        # rows carry all-null block tables and cache_len 0, so their
+        # writes land in the pool's null block and their outputs are
+        # garbage nobody reads (slot-masked continuous batching,
+        # serving/engine.py). S > 1 (a prompt chunk): write the S tokens'
+        # K/V at positions cache_len + i, padding rows (i >= n_valid) at
+        # an out-of-range slot the scatter drops, then attend each query
+        # causally over its row's pages.
         kp, vp, bt, li = paged_cache
         BS = kp.shape[2]
         quant = kp.dtype == jnp.int8
@@ -254,11 +289,25 @@ def attention(p, cfg, rules, x, *, positions, causal=True, kv_x=None,
         else:
             k_w = k.astype(kp.dtype)
             v_w = v.astype(vp.dtype)
-        B = x.shape[0]
-        slot = (bt[jnp.arange(B), cache_len // BS] * BS + cache_len % BS)
-        kp_l, vp_l = kernel_ops.update_kv_buffer(kp_l, vp_l, k_w[:, 0],
-                                                 v_w[:, 0], slot)
-        out = paged_attention_xla(q, kp_l, vp_l, bt, cache_len + 1)
+        B, S = x.shape[:2]
+        if S == 1:
+            slot = (bt[jnp.arange(B), cache_len // BS] * BS
+                    + cache_len % BS)
+            kp_l, vp_l = kernel_ops.update_kv_buffer(kp_l, vp_l, k_w[:, 0],
+                                                     v_w[:, 0], slot)
+            out = paged_attention_xla(q, kp_l, vp_l, bt, cache_len + 1)
+        else:
+            i = jnp.arange(S, dtype=jnp.int32)
+            pos = cache_len[:, None] + i                         # [B,S]
+            blk = jnp.take_along_axis(
+                bt, jnp.minimum(pos // BS, bt.shape[1] - 1), axis=1)
+            slot = jnp.where(i[None] < n_valid[:, None],
+                             blk * BS + pos % BS, kp_l.shape[0] * BS)
+            KVh, hd = k_w.shape[2:]
+            kp_l, vp_l = kernel_ops.update_kv_buffer(
+                kp_l, vp_l, k_w.reshape(B * S, KVh, hd),
+                v_w.reshape(B * S, KVh, hd), slot.reshape(-1))
+            out = paged_chunk_attention_xla(q, kp_l, vp_l, bt, pos)
         kp = jax.lax.dynamic_update_slice_in_dim(kp, kp_l[None], li, axis=0)
         vp = jax.lax.dynamic_update_slice_in_dim(vp, vp_l[None], li, axis=0)
         new_cache = (kp, vp)

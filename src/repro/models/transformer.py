@@ -38,11 +38,12 @@ def init(rng, cfg: ModelConfig):
 
 
 def _block(cfg, rules, p, x, *, positions, cache=None, cache_len=None,
-           carried_cache=None, paged_cache=None):
+           carried_cache=None, paged_cache=None, n_valid=None):
     h, new_cache = L.attention(
         p["attn"], cfg, rules, L.rmsnorm(x, p["ln_attn"]),
         positions=positions, cache=cache, cache_len=cache_len,
-        carried_cache=carried_cache, paged_cache=paged_cache)
+        carried_cache=carried_cache, paged_cache=paged_cache,
+        n_valid=n_valid)
     x = x + h
     if cfg.family == Family.MOE:
         x = x + MOE.moe_mlp(p, cfg, rules, L.rmsnorm(x, p["ln_mlp"]))
@@ -61,12 +62,14 @@ def _remat(fn, cfg):
 
 
 def forward(params, cfg: ModelConfig, rules, tokens, *, embeds=None,
-            positions=None, cache=None, cache_len=None, paged_cache=None):
+            positions=None, cache=None, cache_len=None, paged_cache=None,
+            n_valid=None):
     """tokens: [B,S] int32. embeds: [B,P,D] precomputed prefix (VLM stub).
     cache: stacked {k,v: [L,B,S,KV,hd]} for decode. paged_cache:
     (k_pages, v_pages, block_tables) with arenas [L,NB,BS,KV,hd] shared by
     all sequences and per-row block tables [B,MB] + cache_len [B]
-    (genesys.pagedkv continuous batching). Returns (logits, cache').
+    (genesys.pagedkv continuous batching); with S > 1, n_valid [B] counts
+    each row's real tokens. Returns (logits, cache').
     """
     dt = jnp.dtype(cfg.compute_dtype)
     x = params["embed"].astype(dt)[tokens]
@@ -92,7 +95,7 @@ def forward(params, cfg: ModelConfig, rules, tokens, *, embeds=None,
             h, kp, vp = carry
             h, (kp, vp) = _block(cfg, rules, z["p"], h, positions=positions,
                                  paged_cache=(kp, vp, bt, z["i"]),
-                                 cache_len=cache_len)
+                                 cache_len=cache_len, n_valid=n_valid)
             return (h, kp, vp), None
         xs = {"p": params["blocks"],
               "i": jnp.arange(cfg.n_layers, dtype=jnp.int32)}

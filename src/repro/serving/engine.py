@@ -15,19 +15,27 @@ This engine decodes at one FIXED padded batch shape forever:
     never read — no `where`-masking inside the step function needed.
   * KV lives in the paged arena; a slot's prompt prefix can start
     mid-cache when :class:`~repro.serving.pagedkv.PagedKVPool` has the
-    prefix's blocks sealed (shared-prefix reuse skips those prefill
-    steps entirely).
+    prefix's blocks sealed (shared-prefix reuse skips those positions
+    entirely).
 
-Prompts are consumed by teacher forcing, one token per step (prefill and
-decode share the single-token step function): feeding prompt[i] writes
-its KV at the slot's ``cache_len``; the step that feeds the LAST prompt
-token produces the first generated token. Each generated token is fed
-back until the request's budget is reached; the final token is returned
-but never fed (its KV would be dead).
+Prompts are written in chunks: a second fixed-shape program
+(``train.steps.make_paged_prefill_chunk``) writes up to ``CHUNK`` prompt
+tokens' KV into a slot's blocks in one dispatch. A slot admitted with
+more than its last prompt token left to write joins a FIFO of
+prefilling slots; each step dispatches at most one chunk, for the head
+of the FIFO, covering prompt positions up to P - 2, and then the decode
+step with no sync in between. Meanwhile the slot's decode row stays
+null, like a free slot's. Once its KV reaches P - 1 positions, the slot
+takes its real row with ``cache_len`` P - 1 and the LAST prompt token as
+its current token: the decode step feeds that token and produces the
+first generated token. Each generated token is fed back until the
+request's budget is reached; the final token is returned but never fed
+(its KV would be dead).
 """
 from __future__ import annotations
 
 import time
+from collections import deque
 from dataclasses import dataclass, field
 
 import jax.numpy as jnp
@@ -39,6 +47,8 @@ from repro.core.genesys.trace import (
 )
 from repro.serving.pagedkv import NULL_BLOCK, PagedKVPool, PoolExhausted
 
+CHUNK = 256     # prompt tokens one prefill dispatch writes (16 blocks of 16)
+
 
 @dataclass
 class EngineStats:
@@ -46,8 +56,10 @@ class EngineStats:
     retired: int = 0
     steps: int = 0               # serve_fn dispatches
     step_slots: int = 0          # sum of active slots over steps
-    prefill_steps: int = 0       # prompt tokens fed
+    prefill_steps: int = 0       # prompt tokens fed by decode steps
     prefill_steps_saved: int = 0  # prompt tokens skipped via prefix reuse
+    prefill_chunks: int = 0      # prefill_chunk dispatches
+    prefill_chunk_tokens: int = 0  # prompt tokens the chunks wrote
 
     def occupancy(self) -> float:
         return self.step_slots / max(1, self.steps)
@@ -69,8 +81,10 @@ class ContinuousBatchEngine:
     """Slot-scheduled continuous batching over a paged KV arena."""
 
     def __init__(self, serve_step, params, arenas, pool: PagedKVPool, *,
-                 n_slots: int, max_blocks_per_seq: int, stats=None):
+                 prefill_chunk, n_slots: int, max_blocks_per_seq: int,
+                 stats=None):
         self.serve_step = serve_step
+        self.prefill_chunk = prefill_chunk
         self.params = params
         self.arenas = arenas          # {k,v: [L,NB,BS,KV,hd]}
         self.pool = pool
@@ -81,11 +95,17 @@ class ContinuousBatchEngine:
             raise ValueError("arena/pool block-count mismatch")
         if arenas["k"].shape[2] != pool.block_size:
             raise ValueError("arena/pool block-size mismatch")
+        # every chunk starts on a block boundary (a reused prefix is whole
+        # blocks), so no chunk writes into a shared sealed block
+        if CHUNK % self.block_size:
+            raise ValueError(f"block size {self.block_size} does not "
+                             f"divide the prefill chunk {CHUNK}")
         # fixed-shape schedule state: one row per slot, shapes NEVER change
         self._bt = np.zeros((self.n_slots, self.max_blocks), np.int32)
         self._cl = np.zeros((self.n_slots,), np.int32)
         self._cur = np.zeros((self.n_slots, 1), np.int32)
         self._slots: list[_Slot | None] = [None] * self.n_slots
+        self._prefilling: deque[int] = deque()  # slots awaiting chunks
         # trace.Counters fold: telemetry snapshots of engine stats are
         # torn-read-free even while the decode loop runs (attach_stats)
         self.counters = Counters(EngineStats())
@@ -187,19 +207,52 @@ class ContinuousBatchEngine:
             self._install_block(bid, payload)
         blocks = reused + fresh
         r = len(reused) * bs                # cache positions already filled
-        st = _Slot(meta=meta, prompt=prompt, feed_idx=r + 1, budget=budget,
-                   blocks=blocks, cache_len=r, span=span)
+        st = _Slot(meta=meta, prompt=prompt, budget=budget, blocks=blocks,
+                   cache_len=r, span=span)
         self._slots[slot] = st
-        self._bt[slot, :] = NULL_BLOCK
-        self._bt[slot, :len(blocks)] = blocks
-        self._cl[slot] = r
-        self._cur[slot, 0] = prompt[r]
+        if n - r > 1:
+            self._prefilling.append(slot)   # its decode row stays null
+        else:
+            self._seat(slot, st)
         self.counters.add(admitted=1, prefill_steps_saved=r)
         ch = self.trace
         if ch is not None:
             ch.rec(EV_ADMIT, REQ_SYSNO, span,
                    aux=-1 if tag is None else tag)
         return True
+
+    def _seat(self, slot: int, st: _Slot) -> None:
+        """Give a slot its real row in the decode inputs: the next step
+        feeds ``prompt[cache_len]``, the last prompt token."""
+        self._bt[slot, :] = NULL_BLOCK
+        self._bt[slot, :len(st.blocks)] = st.blocks
+        self._cl[slot] = st.cache_len
+        self._cur[slot, 0] = st.prompt[st.cache_len]
+        st.feed_idx = st.cache_len + 1
+
+    def _dispatch_chunk(self) -> None:
+        """Write the next ``CHUNK`` prompt positions of the FIFO's head
+        slot, stopping before its last prompt token; seat the slot once
+        its chunks are done. Dispatch only: nothing waits on the device."""
+        slot = self._prefilling[0]
+        st = self._slots[slot]
+        start = st.cache_len
+        end = min(start + CHUNK, len(st.prompt) - 1)
+        tokens = np.zeros((1, CHUNK), np.int32)
+        tokens[0, :end - start] = st.prompt[start:end]
+        row = np.full((1, self.max_blocks), NULL_BLOCK, np.int32)
+        row[0, :len(st.blocks)] = st.blocks
+        # numpy inputs: a jnp.asarray of a Python list compiles a program
+        start_a = np.array([start], np.int32)
+        n_valid = np.array([end - start], np.int32)
+        self.arenas = self.prefill_chunk(
+            self.params, self.arenas, jnp.asarray(row), jnp.asarray(tokens),
+            jnp.asarray(start_a), jnp.asarray(n_valid))
+        st.cache_len = end
+        self.counters.add(prefill_chunks=1, prefill_chunk_tokens=end - start)
+        if end == len(st.prompt) - 1:
+            self._prefilling.popleft()
+            self._seat(slot, st)
 
     def _retire(self, slot: int, st: _Slot) -> None:
         ch = self.trace
@@ -218,17 +271,23 @@ class ContinuousBatchEngine:
 
     # ---------------------------------------------------------- decoding ----
     def step(self, loop=None) -> list[tuple[object, list[int]]]:
-        """One fixed-shape decode dispatch for every slot; advances each
-        active slot through prefill or generation and retires finished
-        requests. Returns the ``(meta, generated_tokens)`` pairs that
-        completed on this step. ``loop`` is the serving loop's
+        """At most one prefill chunk, then one fixed-shape decode dispatch
+        for every seated slot; advances each of those slots and retires
+        finished requests. While every busy slot is still prefilling, the
+        chunk goes alone. Returns the ``(meta, generated_tokens)`` pairs
+        that completed on this step. ``loop`` is the serving loop's
         :class:`~repro.core.genesys.trace.LoopRecorder` (tracing on):
         the step marks its dispatch, sync, advance and retire phases."""
-        active = [(i, s) for i, s in enumerate(self._slots) if s is not None]
-        if not active:
+        if not self.n_active:
             return []
         if loop is not None:
             loop.phase(LOOP_DISPATCH)
+        if self._prefilling:
+            self._dispatch_chunk()
+        active = [(i, s) for i, s in enumerate(self._slots)
+                  if s is not None and i not in self._prefilling]
+        if not active:
+            return []
         t0 = time.perf_counter_ns()
         nxt, self.arenas = self.serve_step(
             self.params, self.arenas, jnp.asarray(self._bt),
@@ -303,17 +362,27 @@ def make_engine(cfg, rules, params, *, n_slots: int, n_blocks: int,
     import jax
 
     from repro.models import transformer
-    from repro.train.steps import make_paged_serve_step
+    from repro.train.steps import (make_paged_prefill_chunk,
+                                   make_paged_serve_step)
 
     arenas = transformer.init_paged_arena(cfg, n_blocks, block_size)
     pool = PagedKVPool(n_blocks, block_size)
+    max_blocks = max_blocks_per_seq or n_blocks // 2
     step = make_paged_serve_step(cfg, rules)
+    chunk = make_paged_prefill_chunk(cfg, rules)
     if jit:
         step = jax.jit(step)
+        # compiled here, at its one shape: a window's first prompt must
+        # not wait for it (the arenas are donated, so no second arena
+        # copy is ever live)
+        i32 = jnp.int32
+        chunk = jax.jit(chunk, donate_argnums=1).lower(
+            params, arenas, jnp.zeros((1, max_blocks), i32),
+            jnp.zeros((1, CHUNK), i32), jnp.zeros((1,), i32),
+            jnp.zeros((1,), i32)).compile()
     eng = ContinuousBatchEngine(
-        step, params, arenas, pool, n_slots=n_slots,
-        max_blocks_per_seq=max_blocks_per_seq or n_blocks // 2,
-        stats=stats)
+        step, params, arenas, pool, prefill_chunk=chunk, n_slots=n_slots,
+        max_blocks_per_seq=max_blocks, stats=stats)
     if gsys is not None:
         pool.bind_genesys(gsys, block_bytes=eng.block_bytes(),
                           spill_path=spill_path)

@@ -108,6 +108,14 @@ def make_serve_step(cfg: ModelConfig, rules):
     return serve_step
 
 
+def _paged_api(cfg: ModelConfig):
+    if cfg.family not in (Family.DENSE, Family.MOE, Family.VLM):
+        raise ValueError(
+            f"the paged KV arena supports transformer-family archs, not "
+            f"{cfg.family} (SSM/hybrid state is not block-addressable)")
+    return get_api(cfg)
+
+
 def make_paged_serve_step(cfg: ModelConfig, rules):
     """One paged decode step over the genesys.pagedkv arena:
     (params, arenas {k,v: [L,NB,BS,KV,hd]}, block_tables [B,MB],
@@ -117,11 +125,7 @@ def make_paged_serve_step(cfg: ModelConfig, rules):
     retiring a request changes only block_tables/cache_len row contents,
     never an array shape, so membership churn cannot trigger a re-jit.
     """
-    api = get_api(cfg)
-    if cfg.family not in (Family.DENSE, Family.MOE, Family.VLM):
-        raise ValueError(
-            f"paged decode supports transformer-family archs, not "
-            f"{cfg.family} (SSM/hybrid state is not block-addressable)")
+    api = _paged_api(cfg)
 
     def paged_serve_step(params, arenas, block_tables, token, cache_len):
         logits, new_arenas = api.forward(
@@ -132,3 +136,28 @@ def make_paged_serve_step(cfg: ModelConfig, rules):
         return next_tok, new_arenas
 
     return paged_serve_step
+
+
+def make_paged_prefill_chunk(cfg: ModelConfig, rules):
+    """Write one prompt chunk's KV into the genesys.pagedkv arena:
+    (params, arenas {k,v: [L,NB,BS,KV,hd]}, block_row [1,MB],
+    tokens [1,C], start [1], n_valid [1]) -> new_arenas.
+
+    The C tokens sit at positions start + arange(C) of the row; the first
+    n_valid are the prompt's, the rest padding whose writes are dropped.
+    Each attends causally over the row's pages, so the KV it leaves equals
+    what feeding the tokens one decode step at a time writes. It returns
+    no logits: under jit the unused final norm and output head are dead
+    code. One fixed shape, so the engine compiles it once.
+    """
+    api = _paged_api(cfg)
+
+    def paged_prefill_chunk(params, arenas, block_row, tokens, start,
+                            n_valid):
+        _, new_arenas = api.forward(
+            params, cfg, rules, tokens,
+            paged_cache=(arenas["k"], arenas["v"], block_row),
+            cache_len=start, n_valid=n_valid)
+        return new_arenas
+
+    return paged_prefill_chunk

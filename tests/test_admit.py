@@ -16,7 +16,8 @@ from repro.core.genesys import (
 )
 from repro.core.genesys.executor import EAGAIN, EINTR, EIO
 
-from test_system import _chain, _fake_paged_step, _serve_requests
+from test_system import (_chain, _fake_paged_step, _fake_prefill_chunk,
+                         _serve_requests)
 
 
 @contextmanager
@@ -351,7 +352,8 @@ def test_serve_continuous_protected_admitted_bulk_shed(gsys):
     arenas = {"k": jnp.zeros((1, NB, BS, 1, 1)),
               "v": jnp.zeros((1, NB, BS, 1, 1))}
     eng = ContinuousBatchEngine(_fake_paged_step, {}, arenas,
-                                PagedKVPool(NB, BS), n_slots=2,
+                                PagedKVPool(NB, BS),
+                                prefill_chunk=_fake_prefill_chunk, n_slots=2,
                                 max_blocks_per_seq=4)
     eng.admission = c
     srv = GenesysUdpServer(gsys, port=0, max_batch=4, payload=256,

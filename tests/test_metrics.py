@@ -187,7 +187,8 @@ def test_attach_stats_joins_telemetry_snapshot(gsys):
               "v": jnp.zeros((1, NB, BS, 1, 1))}
     pool = PagedKVPool(NB, BS)
     eng = ContinuousBatchEngine(lambda p, a, bt, cur, cl: (cur[:, 0], a),
-                                {}, arenas, pool, n_slots=2,
+                                {}, arenas, pool,
+                                prefill_chunk=lambda p, a, *_: a, n_slots=2,
                                 max_blocks_per_seq=4)
     gsys.attach_stats("engine", eng.counters)
     gsys.attach_stats("pagedkv", pool.counters)
@@ -334,7 +335,8 @@ def test_request_spans_nest_steps_and_syscalls(tmp_path):
                   "v": jnp.zeros((1, NB, BS, 1, 1))}
         eng = ContinuousBatchEngine(
             lambda p, a, bt, cur, cl: (cur[:, 0] * 2 + 1, a),
-            {}, arenas, PagedKVPool(NB, BS), n_slots=2,
+            {}, arenas, PagedKVPool(NB, BS),
+            prefill_chunk=lambda p, a, *_: a, n_slots=2,
             max_blocks_per_seq=4)
         eng.pool.bind_genesys(g, block_bytes=64)   # MADVISE on retire
         srv = GenesysUdpServer(g, port=0, max_batch=4, payload=256,

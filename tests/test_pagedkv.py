@@ -17,6 +17,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.serving.engine import CHUNK
 from repro.serving.pagedkv import (NULL_BLOCK, PagedKVPool, PoolExhausted,
                                    chain_hashes)
 
@@ -169,11 +170,13 @@ def _model(mesh11):
     return cfg, rules, api, params
 
 
-def _dense_reference(cfg, rules, api, params, prompt, budget):
-    """Teacher-forced prefill + greedy decode on the carried dense cache."""
+def _dense_reference(cfg, rules, api, params, prompt, budget, *,
+                     serve=None, max_len=64):
+    """Teacher-forced prefill + greedy decode on the carried dense cache
+    (``serve``: a ready ``make_serve_step``, for instance a jitted one)."""
     from repro.train.steps import make_serve_step
-    serve = make_serve_step(cfg, rules)
-    cache = api.init_cache(cfg, 1, 64)
+    serve = serve or make_serve_step(cfg, rules)
+    cache = api.init_cache(cfg, 1, max_len)
     toks = [int(t) for t in prompt]
     gen = []
     for i in range(len(prompt) + budget - 1):
@@ -276,3 +279,107 @@ def test_engine_admission_backpressure(mesh11):
             eng.admit(np.asarray([6], np.int32), 5 * BS)
         eng.drain()
     assert eng.pool.stats.blocks_in_use == 0
+
+
+# ------------------------------------------------------ chunked prefill -----
+
+C = CHUNK
+TABLE = 136                  # block-table width: 544 positions
+REUSED = C + 2 * BS          # whole blocks of a retired prompt, reused
+# (prompt length, positions reused from the pool): lengths at and across
+# the chunk edges, one that fills its whole block table with its budget
+# of 3 (the last chunk's padding runs past the table), and one whose
+# chunks start after a reused prefix
+CHUNK_CASES = [(1, 0), (2, 0), (C - 1, 0), (C, 0), (C + 1, 0), (C + 2, 0),
+               (2 * C + 1, 0), (2 * C + 9, 0), (TABLE * BS - 2, 0),
+               (REUSED + C + 2, REUSED)]
+
+
+@pytest.fixture(scope="module")
+def chunk_rig():
+    from repro.launch.mesh import make_host_mesh
+    from repro.train.steps import make_serve_step
+    mesh = make_host_mesh()
+    cfg, rules, api, params = _model(mesh)
+    return mesh, cfg, rules, api, params, jax.jit(make_serve_step(cfg,
+                                                                  rules))
+
+
+def _chunks(p, r):
+    return -(-(p - 1 - r) // C)
+
+
+@pytest.mark.parametrize("plen,reused", CHUNK_CASES,
+                         ids=[f"P{p}-r{r}" for p, r in CHUNK_CASES])
+def test_chunked_engine_matches_dense_reference(chunk_rig, plen, reused):
+    """A prompt written in CHUNK-token dispatches, beside staggered
+    arrivals (a 1-token prompt already decoding, a short prompt queued
+    behind the long one's chunks): every request's tokens equal its solo
+    dense decode, the chunk counters are exact, and no prompt token goes
+    through the decode step but the last."""
+    from repro.serving.engine import EngineStats, make_engine
+    mesh, cfg, rules, api, params, serve = chunk_rig
+    rng = np.random.default_rng([plen, reused])
+
+    def prompt(n):
+        return rng.integers(1, cfg.vocab_size, size=n).astype(np.int32)
+
+    eng = make_engine(cfg, rules, params, n_slots=3, n_blocks=200,
+                      block_size=BS, max_blocks_per_seq=TABLE)
+    long = prompt(plen)
+    with mesh:
+        if reused:
+            # a retired prompt seals its whole blocks in the pool
+            assert eng.admit(np.concatenate([long[:reused], prompt(1)]), 2)
+            eng.drain()
+            eng.stats = EngineStats()
+        # (admitted at step, prompt, budget)
+        reqs = [(0, prompt(1), 6), (1, long, 3), (2, prompt(7), 2)]
+        want = {i: _dense_reference(cfg, rules, api, params, p, b,
+                                    serve=serve, max_len=576)
+                for i, (_, p, b) in enumerate(reqs)}
+        done, n_step = {}, 0
+        while len(done) < len(reqs):
+            for i, (at, p, b) in enumerate(reqs):
+                if at == n_step:
+                    assert eng.admit(p, b, meta=i)
+            for meta, gen in eng.step():
+                done[meta] = gen
+            n_step += 1
+    assert done == want
+    st = eng.stats
+    assert st.prefill_steps_saved == reused
+    assert st.prefill_chunks == _chunks(plen, reused) + _chunks(7, 0)
+    assert st.prefill_chunk_tokens == (plen - 1 - reused) + 6
+    assert st.prefill_steps == 0
+    assert eng.pool.stats.blocks_in_use == 0
+
+
+def test_chunked_prefill_compiles_nothing_after_warm_up(chunk_rig):
+    """make_engine compiles the chunk program at its one shape, so once a
+    1-token prompt has warmed the decode step (as a server warms it), a
+    prompt of several chunks compiles nothing more."""
+    from repro.serving.engine import make_engine
+    mesh, cfg, rules, api, params, _ = chunk_rig
+    compiles = []
+
+    def listen(event, duration, **kw):
+        if "backend_compile" in event:
+            compiles.append(event)
+
+    eng = make_engine(cfg, rules, params, n_slots=2, n_blocks=200,
+                      block_size=BS, max_blocks_per_seq=TABLE)
+    jax.clear_caches()      # as in a fresh process: earlier tests warmed
+    with mesh:
+        eng.admit(np.zeros(1, np.int32), 2)
+        eng.drain()
+        jax.block_until_ready(eng.arenas)
+        jax.monitoring.register_event_duration_secs_listener(listen)
+        try:
+            assert eng.admit(np.arange(1, 2 * C + 3, dtype=np.int32), 2)
+            eng.drain()
+            jax.block_until_ready(eng.arenas)
+        finally:
+            jax.monitoring.unregister_event_duration_listener(listen)
+    assert eng.stats.prefill_chunks == 3
+    assert compiles == []

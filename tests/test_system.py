@@ -186,6 +186,10 @@ def _fake_paged_step(params, arenas, bt, cur, cl):
     return cur[:, 0] * 2 + 1, arenas
 
 
+def _fake_prefill_chunk(params, arenas, row, tokens, start, n_valid):
+    return arenas
+
+
 def _chain(last, n):
     out = []
     for _ in range(n):
@@ -303,7 +307,8 @@ def test_serve_continuous_udp_end_to_end(gsys):
     arenas = {"k": jnp.zeros((1, NB, BS, 1, 1)),
               "v": jnp.zeros((1, NB, BS, 1, 1))}
     eng = ContinuousBatchEngine(_fake_paged_step, {}, arenas,
-                                PagedKVPool(NB, BS), n_slots=2,
+                                PagedKVPool(NB, BS),
+                                prefill_chunk=_fake_prefill_chunk, n_slots=2,
                                 max_blocks_per_seq=4)
     srv = GenesysUdpServer(gsys, port=0, max_batch=4, payload=256,
                            batch_window_s=0.02, use_ring=True)

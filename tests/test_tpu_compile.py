@@ -19,7 +19,9 @@ from repro.configs import get_config
 from repro.launch.serve import serving_config
 from repro.models.registry import get_api
 from repro.sharding import rules_for
-from repro.train.steps import make_paged_serve_step, make_serve_step
+from repro.serving.engine import CHUNK
+from repro.train.steps import (make_paged_prefill_chunk, make_paged_serve_step,
+                               make_serve_step)
 
 # HBM of one v5e chip that XLA may allocate (the compiler's own limit:
 # "... of 15.75G hbm")
@@ -113,6 +115,54 @@ def test_internlm2_20b_paged_step_compiles(one_chip, chip_mesh):
     step = jax.jit(make_paged_serve_step(cfg, rules_for(cfg, chip_mesh)))
     compiled = step.lower(params, arenas, bt, tok, cl).compile()
     assert _peak_bytes(compiled) < V5E_HBM_BYTES
+
+
+def _compile_prefill_chunk(cfg, chip_mesh, one_chip, n_blocks, max_blocks):
+    """The engine's prefill chunk program, jitted as ``make_engine`` jits
+    it (arenas donated), compiled for the chip. Returns the compiled
+    program and the arenas' bytes."""
+    from repro.models import transformer
+    params = _on(_param_shapes(cfg), one_chip)
+    arenas = _on(jax.eval_shape(
+        lambda: transformer.init_paged_arena(cfg, n_blocks, 16)), one_chip)
+    row = jax.ShapeDtypeStruct((1, max_blocks), jnp.int32, sharding=one_chip)
+    tok = jax.ShapeDtypeStruct((1, CHUNK), jnp.int32, sharding=one_chip)
+    one = jax.ShapeDtypeStruct((1,), jnp.int32, sharding=one_chip)
+    chunk = jax.jit(make_paged_prefill_chunk(cfg, rules_for(cfg, chip_mesh)),
+                    donate_argnums=1)
+    compiled = chunk.lower(params, arenas, row, tok, one, one).compile()
+    arena_bytes = sum(x.size * x.dtype.itemsize
+                      for x in jax.tree_util.tree_leaves(arenas))
+    return compiled, arena_bytes
+
+
+def _assert_chunk_program(compiled, arena_bytes):
+    # the arenas are updated in place (donation took), the program fits,
+    # and its name keeps it apart from the decode step in a device trace
+    m = compiled.memory_analysis()
+    assert m.alias_size_in_bytes >= arena_bytes
+    assert _peak_bytes(compiled) < V5E_HBM_BYTES
+    name = compiled.as_text().split(None, 2)[1]
+    assert "prefill_chunk" in name and "paged_serve_step" not in name
+
+
+def test_internlm2_20b_prefill_chunk_compiles(one_chip, chip_mesh):
+    """The engine's CHUNK-token prefill program at internlm2-20b's
+    published widths, depth cut to 4 layers, over the paged step's 256
+    KV blocks of 16 positions, bf16."""
+    cfg = serving_config(replace(get_config("internlm2-20b"), n_layers=4))
+    _assert_chunk_program(*_compile_prefill_chunk(cfg, chip_mesh, one_chip,
+                                                  256, 128))
+
+
+def test_starcoder2_7b_prefill_chunk_fits_one_chip(one_chip, chip_mesh):
+    """The whole published starcoder2-7b (bf16, 13.8 GiB of weights) with
+    its 769-block arena of 48 blocks a sequence: the chunk program fits
+    beside the weights only because the arenas are donated."""
+    cfg = serving_config(get_config("starcoder2-7b"))
+    assert cfg.n_layers == 32 and cfg.d_model == 4608
+    _assert_chunk_program(*_compile_prefill_chunk(cfg, chip_mesh, one_chip,
+                                                  769, 48))
 
 
 def test_invoke_step_compiles(one_chip, gsys):

@@ -444,6 +444,7 @@ def _serve_tiny(trace: bool, monkeypatch, tmp_path=None):
         arenas = {"k": jnp.zeros((1, NB, BS, 1, 1)),
                   "v": jnp.zeros((1, NB, BS, 1, 1))}
         eng = ContinuousBatchEngine(step, {}, arenas, PagedKVPool(NB, BS),
+                                    prefill_chunk=lambda p, a, *_: a,
                                     n_slots=2, max_blocks_per_seq=4)
         eng.pool.bind_genesys(g, block_bytes=64)   # MADVISE on retire
         srv = GenesysUdpServer(g, port=0, max_batch=4, payload=256,

@@ -59,7 +59,8 @@ def main(argv=None) -> int:
     ap.add_argument("--out", default=None)
     args = ap.parse_args(argv)
     import harness
-    cell, m, dev, peaks = harness.prepare(args.workload, tiny=args.tiny_cpu)
+    cell = harness.find_cell(harness.load_benchmark(), args.workload)
+    m, dev, peaks = harness.prepare(cell, tiny=args.tiny_cpu)
     compiles = harness._CompileCounter()
     rows = []
     t_start = T_START

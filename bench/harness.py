@@ -3,10 +3,12 @@ UDP server and the continuous engine, the check against the plain
 reference, and the metrics the cell reports.
 
 Everything a cell is made of is found by name from ``BENCHMARK.json``:
-its configuration file, its traffic mix ``bench/traffic/<mix>.json`` and
-one reader per metric, ``bench/metrics/<metric>.py``. The program is
-driven as a deployment would drive it: ``launch/serve.make_server`` on
-the ring path, ``serving.engine.make_engine``, and
+its configuration file, the architecture module that file names
+(``bench/arch/<arch>.py``: sizes, weights, reference and counts), its
+traffic mix ``bench/traffic/<mix>.json`` and one reader per metric,
+``bench/metrics/<metric>.py``. The program is driven as a deployment
+would drive it: ``launch/serve.make_server`` on the ring path,
+``serving.engine.make_engine``, and
 ``GenesysUdpServer.serve_model_continuous``. Nothing here wraps,
 donates or replaces the program's jitted step.
 """
@@ -31,7 +33,9 @@ import weights as W
 
 BENCH = Path(__file__).resolve().parent
 ROOT = BENCH.parent
+ARCH = BENCH / "arch"
 STEP_MODULE = "paged_serve_step"      # the engine's jitted step, by name
+CHUNK_MODULE = "paged_prefill_chunk"  # ... and its prefill chunk program
 SNAPSHOT_EVERY_S = 4             # seconds between event-ring copies
 
 
@@ -54,13 +58,16 @@ class Cell:
     mix: dict                 # the traffic file
     end_to_end: list
     per_layer: list
+    arch: object              # the module the configuration names
 
 
 def _applies(metric: dict, cell: str) -> bool:
     return "workloads" not in metric or cell in metric["workloads"]
 
 
-def find_cell(bm: dict, name: str) -> Cell:
+def find_cell(bm: dict, name: str, arch_dir: Path = ARCH) -> Cell:
+    """The workload ``name``, its files read and its architecture module
+    loaded from ``arch_dir``."""
     cells = {w["name"]: w for w in bm["workloads"]}
     if name not in cells:
         raise BenchError(f"no workload {name!r} in BENCHMARK.json")
@@ -70,16 +77,37 @@ def find_cell(bm: dict, name: str) -> Cell:
     mix = SCH.load_mix(BENCH / "traffic" / f"{w['traffic']}.json")
     return Cell(name, int(w["chips"]), w["config"], cfg, mix,
                 [m for m in bm["end_to_end"] if _applies(m, name)],
-                [m for m in bm["per_layer"] if _applies(m, name)])
+                [m for m in bm["per_layer"] if _applies(m, name)],
+                load_arch(cfg, conf["file"], arch_dir))
+
+
+def _load(path: Path, module: str):
+    spec = importlib.util.spec_from_file_location(module, path)
+    mod = importlib.util.module_from_spec(spec)
+    sys.modules[module] = mod          # a dataclass looks its module up
+    spec.loader.exec_module(mod)
+    return mod
 
 
 def load_reader(metric: str):
-    path = BENCH / "metrics" / f"{metric}.py"
-    spec = importlib.util.spec_from_file_location(f"bench_metric_{metric}",
-                                                  path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod.read
+    return _load(BENCH / "metrics" / f"{metric}.py",
+                 f"bench_metric_{metric}").read
+
+
+def load_arch(cfg: dict, file: str, where: Path = ARCH):
+    """The module ``<where>/<arch>.py`` that the configuration file
+    ``file`` names under ``"arch"``."""
+    name = cfg.get("arch")
+    if name is None:
+        raise BenchError(f"configuration {file} names no architecture: "
+                         f"it needs \"arch\": \"<module in {where}>\"")
+    path = where / f"{name}.py"
+    if not (isinstance(name, str) and name.isidentifier()
+            and path.is_file()):
+        raise BenchError(f"configuration {file} names architecture "
+                         f"{name!r}, and {where} has no module of that "
+                         f"name")
+    return _load(path, f"bench_arch_{name}")
 
 
 def peaks_for(kind: str) -> dict:
@@ -95,7 +123,7 @@ def peaks_for(kind: str) -> dict:
 class Run:
     """What a window left behind, for the metric readers."""
     cell: Cell
-    dims: W.Dims
+    dims: object                                   # cell.arch.Dims
     seconds: float
     setup_s: float = 0.0
     requests: list = field(default_factory=list)
@@ -122,19 +150,6 @@ class Run:
 
 # ---------------------------------------------------------- the program --
 
-def program_config(name: str, m: W.Dims):
-    """The program's ``ModelConfig`` for a configuration file's sizes,
-    with weights in the compute dtype as ``launch/serve`` serves them."""
-    from repro.config import Family, ModelConfig
-    from repro.launch.serve import serving_config
-
-    return serving_config(ModelConfig(
-        arch_id=name, family=Family.DENSE, n_layers=m.layers, d_model=m.d,
-        n_heads=m.heads, n_kv_heads=m.kv_heads, d_ff=m.ff,
-        vocab_size=m.vocab, head_dim=m.hd, rope_theta=m.theta,
-        mlp_kind="swiglu" if m.gated else "gelu", remat="none"))
-
-
 def check_layout(mc, params) -> None:
     """The weights must have exactly the program's own tree and shapes."""
     import jax
@@ -157,15 +172,15 @@ class Model:
     params: object
 
 
-def build_model(cell: Cell, m: W.Dims, seed: int) -> Model:
+def build_model(cell: Cell, m, seed: int) -> Model:
     import jax
     from repro.launch.mesh import make_host_mesh
     from repro.sharding import rules_for
 
-    mc = program_config(cell.config_name, m)
+    mc = cell.arch.program_config(cell.config_name, m)
     mesh = make_host_mesh()
     rules = rules_for(mc, mesh)
-    params = W.make_program_params(m, seed)
+    params = W.make_program_params(cell.arch, m, seed)
     check_layout(mc, params)
     jax.block_until_ready(params)
     return Model(mc, mesh, rules, params)
@@ -189,7 +204,7 @@ class _CompileCounter:
         jax.monitoring.register_event_duration_secs_listener(listen)
 
 
-def serve_window(cell: Cell, model: Model, m: W.Dims, sched: SCH.Schedule,
+def serve_window(cell: Cell, model: Model, m, sched: SCH.Schedule,
                  seconds: float, *, t_start: float, trace: bool,
                  workdir: Path, compiles: _CompileCounter,
                  held: dict, keep_trace: Path | None = None) -> "Run":
@@ -313,7 +328,8 @@ def serve_window(cell: Cell, model: Model, m: W.Dims, sched: SCH.Schedule,
     if trace:
         import tracefile
         tr = tracefile.load_xplane(tracefile.find_xplane(str(prof_dir)))
-        run.trace = tracefile.reduce(tr, STEP_MODULE)
+        run.trace = tracefile.reduce(tr, STEP_MODULE,
+                                     chunk_module=CHUNK_MODULE)
         if keep_trace is not None:
             keep_sample(tr, keep_trace)
     return run
@@ -405,7 +421,8 @@ def check(run: Run, cell: Cell, sched: SCH.Schedule, seed: int, *,
            "wrong_length": {"value": wrong_length, "limit": 0}}
     gaps = cgaps = np.zeros(0)
     if sample:
-        ref = R.Reference(cell.cfg, seed, ck["sample_rows"], positions)
+        ref = R.Reference(cell.arch, cell.cfg, seed, ck["sample_rows"],
+                          positions)
         prompts = [sched.prompts[i] for i in sample]
         served = [run.requests[i]["tokens"] for i in sample]
         gaps = R.served_gaps(ref, prompts, served)
@@ -451,19 +468,18 @@ def enable_cache() -> None:
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
 
 
-def prepare(name: str, *, tiny: bool = False):
-    """The cell, its model's sizes, the device (checked) and its peaks."""
+def prepare(cell: Cell, *, tiny: bool = False):
+    """The cell's model sizes, the device (checked) and its peaks."""
     sys.path.insert(0, str(ROOT / "src"))
-    cell = find_cell(load_benchmark(), name)
     if tiny:
         shrink(cell)
     dev = device_info(cell.chips, require_tpu=not tiny)
     peaks = None if tiny else peaks_for(dev["kind"])
     enable_cache()
-    return cell, W.dims(cell.cfg), dev, peaks
+    return cell.arch.dims(cell.cfg), dev, peaks
 
 
-def one_window(cell: Cell, m: W.Dims, seed: int, seconds: float, *,
+def one_window(cell: Cell, m, seed: int, seconds: float, *,
                trace: bool, t_start: float, compiles: _CompileCounter,
                rate_per_s: float | None = None,
                control: bool = False,
@@ -489,11 +505,11 @@ def one_window(cell: Cell, m: W.Dims, seed: int, seconds: float, *,
     return run, res
 
 
-def run_cell(name: str, seed: int, seconds: float, trace: bool, *,
+def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, *,
              t_start: float, tiny: bool = False,
              keep_trace: Path | None = None) -> dict:
-    """One run of cell ``name``: the result line's object."""
-    cell, m, dev, peaks = prepare(name, tiny=tiny)
+    """One run of ``cell``: the result line's object."""
+    m, dev, peaks = prepare(cell, tiny=tiny)
     run, res = one_window(cell, m, seed, seconds, trace=trace,
                           t_start=t_start, compiles=_CompileCounter(),
                           keep_trace=keep_trace)
@@ -535,9 +551,6 @@ def result_line(cell: Cell, run: Run, res: dict, dev: dict, trace: bool,
 
 # -------------------------------------------------- the CPU dry run size --
 
-TINY = {"num_hidden_layers": 2, "hidden_size": 64, "num_attention_heads": 4,
-        "num_key_value_heads": 2, "intermediate_size": 128,
-        "vocab_size": 512, "head_dim": 16}
 TINY_SERVING = {"n_slots": 4, "block_size": 16, "n_blocks": 48,
                 "max_blocks_per_seq": 6}
 
@@ -545,7 +558,7 @@ TINY_SERVING = {"n_slots": 4, "block_size": 16, "n_blocks": 48,
 def shrink(cell: Cell) -> None:
     """A tiny copy of the cell for a dry run on the CPU: the same path,
     the same traffic shape, sizes a test can hold."""
-    cell.cfg = dict(cell.cfg, **TINY)
+    cell.cfg = dict(cell.cfg, **cell.arch.TINY)
     cell.cfg["serving"] = dict(cell.cfg["serving"], **TINY_SERVING)
     cell.cfg["check"] = dict(cell.cfg["check"], sample_rows=4,
                              min_tokens_compared=1)

@@ -1,7 +1,8 @@
 """Model operations of the tokens the engine's steps processed, over the
 device time of those steps times the chip's bf16 peak, in percent. The
-operations count only the active slots' tokens, each attending over its
-own live context (``counts.py``)."""
+operations count only the seated slots' tokens, each attending over its
+own live context, its prompt included (the cell's architecture module,
+``arch/<arch>.py``, counts them)."""
 from readings import step_work
 
 

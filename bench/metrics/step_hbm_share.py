@@ -1,7 +1,8 @@
 """Bytes the algorithm needs per engine step (weights once, the live KV
-read, the new KV written; ``counts.py``) over the step's device time
-times the chip's HBM bandwidth, in percent. Copies of the whole arena
-and dense gathers are not needed bytes: this share exposes them."""
+read, the new KV written; the cell's architecture module counts them)
+over the step's device time times the chip's HBM bandwidth, in
+percent. Copies of the whole arena and dense gathers are not needed
+bytes: this share exposes them."""
 from readings import step_work
 
 

@@ -26,17 +26,19 @@ def engine_delta(run, field: str) -> int:
 
 def step_work(run):
     """Mean per-step operations and bytes of the engine steps that began
-    in the window (from the program's step spans), and the mean device
-    time of one step (from the device trace), or None."""
-    import counts
+    in the window (the cell's architecture counts them from the
+    program's step spans), and the mean device time of one step (from
+    the device trace), or None."""
     import spans
 
     if run.events is None or run.trace is None:
         return None
     dev = run.trace["step_device_s"]
-    ctx = spans.step_contexts(run.events, run.w0_ns, run.w1_ns)
+    ctx = spans.step_contexts(run.events, run.w0_ns, run.w1_ns,
+                              [r["prompt_len"] for r in run.requests])
     if not dev or not ctx:
         return None
-    flops = sum(counts.step_flops(run.dims, c) for c in ctx) / len(ctx)
-    nbytes = sum(counts.step_bytes(run.dims, c) for c in ctx) / len(ctx)
+    arch, steps = run.cell.arch, [spans.Step(c) for c in ctx]
+    flops = sum(arch.step_flops(run.dims, s) for s in steps) / len(steps)
+    nbytes = sum(arch.step_bytes(run.dims, s) for s in steps) / len(steps)
     return flops, nbytes, sum(dev) / len(dev)

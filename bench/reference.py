@@ -1,22 +1,23 @@
-"""The plain reference: the model's full forward pass in float32 with no
-cache, no batching across requests and no kernels, written from the
-published description (RMSNorm, rotary positions on half-rotated pairs,
-grouped-query causal attention, SwiGLU or tanh-GELU MLP) and importing
-nothing of the program. It regenerates the weights from the seed
-(``weights.py``) one layer at a time, so the whole model never sits on
-the device in float32.
+"""The plain reference's shared part: the numerics every architecture's
+reference is written with, the pass over its blocks, and the comparison
+with the served tokens. It imports nothing of the program.
+
+An architecture module (``arch/<arch>.py``) gives the pieces: its
+embedding, its blocks of layers and its head, each in float32, and
+each with a ``control`` switch that computes it one precision below the
+configuration's instead. They regenerate the weights from the seed one
+layer at a time, so the whole model never sits on the device in
+float32.
 
 ``served_gaps`` reads, for each token the server sent back, how far the
 reference's logit for it lies below the reference's best at that
-position. ``control=True`` runs the same pass a second time computed in
-float8 (e4m3), the precision one step below the configuration's
-bfloat16: both operands of every matrix product with a weight are
-rounded to float8, weights with one scale per output channel and
-activations with one scale per token, and so are the queries, keys and
-values (a float8 KV cache), with one scale per head vector. It reads
-the gap of the token that pass puts first instead.
+position. ``control=True`` runs the same pass a second time with the
+control on, and reads the gap of the token that pass puts first
+instead.
 """
 from __future__ import annotations
+
+from functools import partial
 
 import jax
 import jax.numpy as jnp
@@ -28,7 +29,7 @@ HI = jax.lax.Precision.HIGHEST
 F8_MAX = 448.0
 
 
-def _mm(spec, *xs):
+def mm(spec, *xs):
     return jnp.einsum(spec, *xs, precision=HI,
                       preferred_element_type=jnp.float32)
 
@@ -57,94 +58,32 @@ def fp8_round(w, in_axes):
     return (w / s).astype(jnp.float8_e4m3fn).astype(jnp.float32) * s
 
 
-def _same(x, axes):
+def same(x, axes):
+    """The rounding of the float32 pass: none."""
     return x
 
 
-def attend(m: W.Dims, h, w, pos, rnd=_same):
-    """One sequence: h [S, d] -> [S, d]."""
-    h = rnd(h, (1,))
-    q = rotary(_mm("sd,dnk->snk", h, w["attn", "wq"]), pos, m.theta)
-    k = rotary(_mm("sd,dnk->snk", h, w["attn", "wk"]), pos, m.theta)
-    v = _mm("sd,dnk->snk", h, w["attn", "wv"])
-    q, k, v = rnd(q, (2,)), rnd(k, (2,)), rnd(v, (2,))
-    g = m.heads // m.kv_heads
-    q = q.reshape(q.shape[0], m.kv_heads, g, m.hd)
-    s = _mm("qkgh,tkh->kgqt", q, k) / np.sqrt(m.hd)
-    causal = pos[:, None] >= pos[None, :]
-    s = jnp.where(causal[None, None], s, -jnp.inf)
-    p = jax.nn.softmax(s, axis=-1)
-    o = _mm("kgqt,tkh->qkgh", p, v).reshape(q.shape[0], m.heads, m.hd)
-    return _mm("snk,nkd->sd", rnd(o, (1, 2)), w["attn", "wo"])
-
-
-def mlp(m: W.Dims, h, w, rnd=_same):
-    h = rnd(h, (1,))
-    u = _mm("sd,df->sf", h, w["mlp", "wi_up"])
-    if m.gated:
-        a = jax.nn.silu(_mm("sd,df->sf", h, w["mlp", "wi_gate"])) * u
-    else:
-        a = jax.nn.gelu(u, approximate=True)
-    return _mm("sf,fd->sd", rnd(a, (1,)), w["mlp", "wo"])
-
-
-def layer(m: W.Dims, x, w, rnd=_same):
-    """x [B, S, d] float32; norm scales are ones."""
-    pos = jnp.arange(x.shape[1])
-
-    def one(xs):
-        xs = xs + attend(m, rmsnorm(xs, m.eps), w, pos, rnd)
-        return xs + mlp(m, rmsnorm(xs, m.eps), w, rnd)
-    return jax.lax.map(one, x)
-
-
-_IN_AXES = {("attn", "wq"): (0,), ("attn", "wk"): (0,), ("attn", "wv"): (0,),
-            ("attn", "wo"): (0, 1), ("mlp", "wi_gate"): (0,),
-            ("mlp", "wi_up"): (0,), ("mlp", "wo"): (0,)}
-
-
 class Reference:
-    """Jitted pieces of the reference for one configuration, compiled for
-    a fixed ``[rows, positions]`` so every run reuses them."""
+    """Jitted pieces of one architecture's reference for one
+    configuration, compiled for a fixed ``[rows, positions]`` so every
+    run reuses them."""
 
-    def __init__(self, cfg: dict, seed: int, rows: int, positions: int):
-        self.m = m = W.dims(cfg)
+    def __init__(self, arch, cfg: dict, seed: int, rows: int,
+                 positions: int):
+        self.m = m = arch.dims(cfg)
         self.key = W.base_key(seed)
         self.shape = (rows, positions)
-
-        def weights_of(key, i, control):
-            w = W.one_layer(m, key, i, jnp.bfloat16)
-            w = {k: v.astype(jnp.float32) for k, v in w.items()}
-            if control:
-                w = {k: fp8_round(v, _IN_AXES[k]) for k, v in w.items()}
-            return w
-
-        def run_layer(x, key, i, control):
-            return layer(m, x, weights_of(key, i, control),
-                         fp8_round if control else _same)
-
-        def embed(key, tokens, control):
-            e = W.embedding(m, key, jnp.bfloat16).astype(jnp.float32)
-            if control:
-                e = fp8_round(e, (1,))
-            return e[tokens]
-
-        def logits(key, h, control):
-            w = W.head(m, key, jnp.bfloat16).astype(jnp.float32)
-            h = rmsnorm(h, m.eps)
-            if control:
-                w, h = fp8_round(w, (0,)), fp8_round(h, (1,))
-            return _mm("td,dv->tv", h, w)[:, :m.vocab]
-
-        self._layer = jax.jit(run_layer, static_argnums=3)
-        self._embed = jax.jit(embed, static_argnums=2)
-        self._logits = jax.jit(logits, static_argnums=2)
+        self._embed = jax.jit(partial(arch.ref_embed, m), static_argnums=2)
+        self._blocks = [(jax.jit(partial(fn, m), static_argnums=3), ids)
+                        for fn, ids in arch.ref_blocks(m)]
+        self._logits = jax.jit(partial(arch.ref_logits, m), static_argnums=2)
 
     def hidden(self, tokens: np.ndarray, control: bool = False):
         """Final hidden states [rows, positions, d] of padded tokens."""
         x = self._embed(self.key, jnp.asarray(tokens), control)
-        for i in range(self.m.layers):
-            x = self._layer(x, self.key, jnp.int32(i), control)
+        for layer, ids in self._blocks:
+            for i in ids:
+                x = layer(x, self.key, jnp.int32(i), control)
         return x
 
     def head_logits(self, h, control: bool = False):
@@ -163,8 +102,8 @@ def served_gaps(ref: Reference, prompts: list[np.ndarray],
                 chunk: int = 512) -> np.ndarray:
     """Per served token, ``max(ref logits) - ref logit of the token`` at
     the position that produced it (``inf`` for an id outside the
-    vocabulary). With ``control``, the token is the one the float8 pass
-    puts first at that position, not the served one."""
+    vocabulary). With ``control``, the token is the one the control's
+    pass puts first at that position, not the served one."""
     rows, positions = ref.shape
     seqs, where = [], []
     for r, (p, g) in enumerate(zip(prompts, served)):

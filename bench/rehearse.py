@@ -1,6 +1,7 @@
-"""Compile a configuration's paged serve step and weight maker for one
-TPU v5e chip that is described, not attached, and print what the
-compiler says they need (``memory_analysis``).
+"""Compile a configuration's paged serve step and weight maker (both
+through the architecture module its file names) for one TPU v5e chip
+that is described, not attached, and print what the compiler says they
+need (``memory_analysis``).
 
   JAX_PLATFORMS=cpu python bench/rehearse.py internlm2-20b [--blocks N ...]
   JAX_PLATFORMS=cpu python bench/rehearse.py starcoder2-7b --search
@@ -27,7 +28,6 @@ import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 
 import harness  # noqa: E402
-import weights as W  # noqa: E402
 
 # HBM of one v5e chip that XLA may allocate (the compiler's own limit)
 V5E_HBM_BYTES = 15.75 * 2**30
@@ -62,24 +62,25 @@ def _report(what: str, ma) -> dict:
     return row
 
 
-def compile_weights(m: W.Dims, one_chip):
-    fn = jax.jit(lambda k: W.program_params(m, k), out_shardings=one_chip)
+def compile_weights(arch, m, one_chip):
+    fn = jax.jit(lambda k: arch.program_params(m, k), out_shardings=one_chip)
     key = jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=one_chip)
     return fn.lower(key).compile().memory_analysis()
 
 
-def compile_step(cfg: dict, name: str, n_blocks: int, one_chip, mesh):
+def compile_step(arch, cfg: dict, name: str, n_blocks: int, one_chip,
+                 mesh):
     from repro.models import transformer
     from repro.sharding import rules_for
     from repro.train.steps import make_paged_serve_step
 
-    m = W.dims(cfg)
+    m = arch.dims(cfg)
     sv = cfg["serving"]
-    mc = harness.program_config(name, m)
+    mc = arch.program_config(name, m)
     on = lambda t: jax.tree_util.tree_map(  # noqa: E731
         lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip),
         t)
-    params = on(jax.eval_shape(lambda k: W.program_params(m, k),
+    params = on(jax.eval_shape(lambda k: arch.program_params(m, k),
                                jax.random.PRNGKey(0)))
     arenas = on(jax.eval_shape(lambda: transformer.init_paged_arena(
         mc, n_blocks, sv["block_size"])))
@@ -99,24 +100,25 @@ def main(argv=None) -> int:
     ap.add_argument("--margin-gib", type=float, default=0.25)
     args = ap.parse_args(argv)
     jax.config.update("jax_enable_compilation_cache", False)
-    cfg = json.loads((BENCH / "configs" / f"{args.config}.json").read_text())
+    file = f"bench/configs/{args.config}.json"
+    cfg = json.loads((BENCH.parent / file).read_text())
+    arch = harness.load_arch(cfg, file)
     one_chip, mesh = _chip()
-    _report("weights", compile_weights(W.dims(cfg), one_chip))
+    _report("weights", compile_weights(arch, arch.dims(cfg), one_chip))
     blocks = args.blocks or [cfg["serving"]["n_blocks"]]
-    rows = {n: _report(f"step n_blocks={n}",
-                       compile_step(cfg, args.config, n, one_chip, mesh))
-            for n in blocks}
+
+    def step(n):
+        return compile_step(arch, cfg, args.config, n, one_chip, mesh)
+    rows = {n: _report(f"step n_blocks={n}", step(n)) for n in blocks}
     if args.search:
         lo, hi = 256, 1024
         for n in (lo, hi):
             if n not in rows:
-                rows[n] = _report(f"step n_blocks={n}", compile_step(
-                    cfg, args.config, n, one_chip, mesh))
+                rows[n] = _report(f"step n_blocks={n}", step(n))
         per_block = (rows[hi]["peak_gib"] - rows[lo]["peak_gib"]) / (hi - lo)
         room = V5E_HBM_BYTES / GIB - args.margin_gib - rows[lo]["peak_gib"]
         best = lo + int(room / per_block)
-        row = _report(f"step n_blocks={best}",
-                      compile_step(cfg, args.config, best, one_chip, mesh))
+        row = _report(f"step n_blocks={best}", step(best))
         print(json.dumps({"per_block_gib": per_block, "n_blocks": best,
                           "headroom_gib": V5E_HBM_BYTES / GIB
                           - row["peak_gib"]}))

@@ -34,8 +34,9 @@ def main(argv=None) -> int:
     args = ap.parse_args(argv)
     import harness
     try:
+        cell = harness.find_cell(harness.load_benchmark(), args.workload)
         line = harness.run_cell(
-            args.workload, args.seed, args.seconds, bool(args.trace),
+            cell, args.seed, args.seconds, bool(args.trace),
             t_start=T_START, tiny=args.tiny_cpu,
             keep_trace=Path(args.keep_trace) if args.keep_trace else None)
     except harness.BenchError as e:

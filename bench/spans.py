@@ -4,16 +4,19 @@ step's slots attended over, and the time from submission to completion
 of each call.
 
 Event codes are the program's (``EV_SUBMIT`` 1, ``EV_COMPLETE`` 5,
-``EV_IRQ`` 7, ``EV_STEP`` 13); a ``STEP`` event is written
-once per active request per engine step, with the step's index in
-``sysno``, the request's span id in ``seq`` and the step's start in
-``ts``.
+``EV_IRQ`` 7, ``EV_STEP`` 13, ``EV_ADMIT`` 15); a ``STEP`` event is
+written once per seated request per engine step, with the step's index
+in ``sysno``, the request's span id in ``seq`` and the step's start in
+``ts``; an ``ADMIT`` event has the span in ``seq`` and the request's
+wire tag, its index in the schedule, in ``aux``.
 """
 from __future__ import annotations
 
+from dataclasses import dataclass, field
+
 import numpy as np
 
-EV_SUBMIT, EV_COMPLETE, EV_IRQ, EV_STEP = 1, 5, 7, 13
+EV_SUBMIT, EV_COMPLETE, EV_IRQ, EV_STEP, EV_ADMIT = 1, 5, 7, 13, 15
 _KEY = np.int64(1) << np.int64(44)
 
 
@@ -29,11 +32,25 @@ def merge(snapshots: list[np.ndarray]) -> np.ndarray:
     return out[np.argsort(out["ts"], kind="stable")]
 
 
-def step_contexts(evs: np.ndarray, w0: int, w1: int) -> list[list[int]]:
+@dataclass
+class Step:
+    """One engine step, as an architecture's counts read it: each seated
+    slot's context, and per-step counters that an architecture's spans
+    add (none yet)."""
+    contexts: list[int]
+    counters: dict = field(default_factory=dict)
+
+
+def step_contexts(evs: np.ndarray, w0: int, w1: int,
+                  prompt_len: list[int]) -> list[list[int]]:
     """For each engine step that started inside ``[w0, w1)``, the number
-    of positions each active slot's token attended over. A request's
-    n-th step (from 1) attends over n positions; every request of the
-    window began inside it, so its earlier steps are all in ``evs``."""
+    of positions each seated slot's token attended over. A request is
+    seated once its prompt but the last token is in the cache (chunked
+    prefill), so its n-th step (from 1) attends over ``P - 1 + n``
+    positions, ``P`` its prompt's length: ``prompt_len[tag]``, the tag
+    joined from its ``ADMIT`` by span. A request with no such ``ADMIT``
+    counts ``P`` as 1. Every request of the window began inside it, so
+    its earlier steps are all in ``evs``."""
     st = evs[evs["ev"] == EV_STEP]
     if not len(st):
         return []
@@ -42,7 +59,12 @@ def step_contexts(evs: np.ndarray, w0: int, w1: int) -> list[list[int]]:
     new_span = np.concatenate([[True], st["seq"][1:] != st["seq"][:-1]])
     start_idx = np.maximum.accumulate(np.where(new_span,
                                                np.arange(len(st)), 0))
-    context = np.arange(len(st)) - start_idx + 1
+    adm = evs[evs["ev"] == EV_ADMIT]
+    prompt = {int(span): int(prompt_len[tag])
+              for span, tag in zip(adm["seq"], adm["aux"])
+              if 0 <= tag < len(prompt_len)}
+    first = np.asarray([prompt.get(int(s), 1) for s in st["seq"]], np.int64)
+    context = np.arange(len(st)) - start_idx + first
     inside = (st["ts"] >= w0) & (st["ts"] < w1)
     steps: dict = {}
     for k, c in zip(st["sysno"][inside], context[inside]):

@@ -1,5 +1,5 @@
-"""The control: the reference computed one precision below the
-configuration's bfloat16 (float8 e4m3, ``reference.py``) must fail the
+"""The control: the dense reference computed one precision below the
+configuration's bfloat16 (float8 e4m3, ``arch/dense.py``) must fail the
 check's limit. On the chip it ran at each cell's own size (PERF.md);
 here at a size a test run holds, with every width cut to 256 and the
 vocabulary to 4096, on three seeds."""
@@ -10,6 +10,7 @@ import numpy as np
 import pytest
 
 import reference as R
+from arch import dense
 
 BENCH = Path(__file__).resolve().parents[1]
 SMALL = dict(hidden_size=256, num_attention_heads=4, num_key_value_heads=2,
@@ -24,7 +25,7 @@ def test_float8_control_fails_the_limit(config, seed):
     limit = cfg["check"]["widest_gap_limit"]
     cfg.update(SMALL)
     rng = np.random.default_rng(seed)
-    ref = R.Reference(cfg, seed, 4, 128)
+    ref = R.Reference(dense, cfg, seed, 4, 128)
     prompts = [rng.integers(0, 4096, 40, dtype=np.int32) for _ in range(4)]
     served = [rng.integers(0, 4096, 80).tolist() for _ in range(4)]
     control = R.served_gaps(ref, prompts, served, control=True)
@@ -38,7 +39,7 @@ def test_float8_control_fails_the_limit(config, seed):
 def test_the_reference_own_choice_has_no_gap():
     cfg = json.loads((BENCH / "configs" / "internlm2-20b.json").read_text())
     cfg.update(SMALL)
-    ref = R.Reference(cfg, 3, 1, 32)
+    ref = R.Reference(dense, cfg, 3, 1, 32)
     prompt = np.arange(1, 9, dtype=np.int32)
     toks = list(prompt)
     served = []

@@ -42,7 +42,7 @@ def test_tiny_run_is_correct_and_names_its_device(cell, trace):
                       if m["source"] in DEVICE_SOURCES}
     assert not device_metrics & set(line["metrics"])
     if trace:
-        assert "prefill_slot_share" in line["metrics"]
+        assert "prefill_p50_ms" in line["metrics"]
         assert "busy_s" in line["device"] and "breakdown" in line
     else:
         assert {"setup_s", "out_tokens_per_s", "req_latency_p50_ms",

@@ -38,8 +38,9 @@ def _broken(kind):
 def test_broken_step_is_not_correct(cell, kind, monkeypatch):
     from repro.train import steps
     monkeypatch.setattr(steps, "make_paged_serve_step", _broken(kind))
-    line = harness.run_cell(cell, 2**31 + 99, 3.0, False,
-                            t_start=time.monotonic(), tiny=True)
+    line = harness.run_cell(harness.find_cell(harness.load_benchmark(), cell),
+                            2**31 + 99, 3.0, False, t_start=time.monotonic(),
+                            tiny=True)
     assert line["correct"] is False, line["check"]
     assert line["check"]["widest_gap"]["value"] > \
         line["check"]["widest_gap"]["limit"]
@@ -47,6 +48,7 @@ def test_broken_step_is_not_correct(cell, kind, monkeypatch):
 
 @pytest.mark.parametrize("cell", CELLS)
 def test_sound_step_is_correct(cell):
-    line = harness.run_cell(cell, 2**31 + 99, 3.0, False,
-                            t_start=time.monotonic(), tiny=True)
+    line = harness.run_cell(harness.find_cell(harness.load_benchmark(), cell),
+                            2**31 + 99, 3.0, False, t_start=time.monotonic(),
+                            tiny=True)
     assert line["correct"] is True, line["check"]

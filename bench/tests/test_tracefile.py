@@ -34,6 +34,16 @@ def test_busy_steps_and_ops_by_hand():
     assert dict(r["device_ops"])["fusion.1"] == pytest.approx(3e-3)
 
 
+def test_chunk_module_time_beside_the_steps():
+    tr = hand_trace()
+    tr["modules"].append(["jit_paged_prefill_chunk(7)", 6 * MS, 2 * MS])
+    r = tracefile.reduce(tr, "paged_serve_step",
+                         chunk_module="paged_prefill_chunk")
+    assert r["chunk_device_s"] == pytest.approx([2e-3])
+    assert r["step_device_s"] == pytest.approx([6e-3, 4e-3])
+    assert tracefile.reduce(tr, "paged_serve_step")["chunk_device_s"] == []
+
+
 def test_idle_gaps_longest_first_with_the_innermost_host_label():
     r = tracefile.reduce(hand_trace(), "paged_serve_step")
     # [6,10): only "outer" covers its middle; [3,5): the Pjit call does

@@ -1,4 +1,5 @@
-"""The reference regenerates exactly the weights the program serves."""
+"""The dense architecture's reference regenerates exactly the weights the
+program serves."""
 import json
 from pathlib import Path
 
@@ -7,6 +8,7 @@ import numpy as np
 import pytest
 
 import weights as W
+from arch import dense
 
 BENCH = Path(__file__).resolve().parents[1]
 
@@ -17,19 +19,19 @@ def test_layer_by_layer_equals_the_stacked_tree(config):
     cfg.update(hidden_size=64, num_attention_heads=4, num_key_value_heads=2,
                intermediate_size=96, vocab_size=300, num_hidden_layers=3,
                head_dim=16)
-    m = W.dims(cfg)
+    m = dense.dims(cfg)
     seed = 2**31 + 11
-    tree = W.make_program_params(m, seed)
+    tree = W.make_program_params(dense, m, seed)
     key = W.base_key(seed)
     for i in range(m.layers):
-        one = W.one_layer(m, key, i, jnp.bfloat16)
+        one = dense.one_layer(m, key, i, jnp.bfloat16)
         for (group, name), v in one.items():
             assert np.array_equal(np.asarray(tree["blocks"][group][name][i]),
                                   np.asarray(v)), (group, name, i)
     assert np.array_equal(np.asarray(tree["embed"]),
-                          np.asarray(W.embedding(m, key, jnp.bfloat16)))
+                          np.asarray(dense.embedding(m, key, jnp.bfloat16)))
     assert np.array_equal(np.asarray(tree["lm_head"]),
-                          np.asarray(W.head(m, key, jnp.bfloat16)))
+                          np.asarray(dense.head(m, key, jnp.bfloat16)))
     assert ("mlp", "wi_gate") in one if m.gated else \
         ("mlp", "wi_gate") not in one
     # padded vocabulary rows and columns are zero

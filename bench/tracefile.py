@@ -92,14 +92,20 @@ def host_label(host, t0: float, t1: float) -> str:
     return best
 
 
-def reduce(tr: dict, step_module: str, top: int = 10) -> dict:
+def reduce(tr: dict, step_module: str, top: int = 10,
+           chunk_module: str | None = None) -> dict:
     """Device busy time (union of operation intervals), the device time
     of each execution of the program whose name contains
-    ``step_module``, the operations that took most time, and the longest
-    idle gaps labelled by what the host was doing in them."""
+    ``step_module`` (and of ``chunk_module``'s, if given), the
+    operations that took most time, and the longest idle gaps labelled
+    by what the host was doing in them."""
     busy_iv = union(_intervals(tr["ops"]))
     busy_s = float((busy_iv[:, 1] - busy_iv[:, 0]).sum()) / 1e9
-    steps = [d / 1e9 for name, _, d in tr["modules"] if step_module in name]
+
+    def module_s(part):
+        return [d / 1e9 for name, _, d in tr["modules"] if part in name]
+    steps = module_s(step_module)
+    chunks = module_s(chunk_module) if chunk_module else []
     by_op: dict = {}
     for name, _, d in tr["ops"]:
         key = op_name(name)
@@ -112,4 +118,5 @@ def reduce(tr: dict, step_module: str, top: int = 10) -> dict:
     idle = [[host_label(tr["host"], a, b), g / 1e9]
             for g, a, b in gaps[:top]]
     return {"busy_s": busy_s, "step_device_s": steps,
+            "chunk_device_s": chunks,
             "device_ops": [[n, s] for n, s in ops], "idle_gaps": idle}

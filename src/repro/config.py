@@ -30,15 +30,27 @@ class ModelConfig:
     d_ff: int
     vocab_size: int
     head_dim: int = 0                     # 0 -> d_model // n_heads
+    norm_eps: float = 1e-6                # RMSNorm epsilon
     qkv_bias: bool = False                # qwen2
     mlp_kind: str = "swiglu"              # swiglu (3 mats) | gelu (2 mats)
     rope_theta: float = 1e6
     tie_embeddings: bool = False
+    # --- latent attention (MLA, deepseek_v3); kv_lora_rank 0 -> GQA ---
+    kv_lora_rank: int = 0                 # latent width cached per token
+    qk_nope_head_dim: int = 0             # query/key width without rotary
+    qk_rope_head_dim: int = 0             # rotary width (one shared key head)
+    v_head_dim: int = 0
     # --- MoE ---
-    n_experts: int = 0
+    n_experts: int = 0                    # routed experts the router scores
     top_k: int = 0
     dense_residual: bool = False          # arctic: dense MLP in parallel w/ MoE
     capacity_factor: float = 1.25
+    router: str = "softmax"               # softmax | sigmoid_bias (noaux_tc)
+    routed_scale: float = 1.0             # routed_scaling_factor
+    shared_d_ff: int = 0                  # shared experts as one SwiGLU
+    n_dense_layers: int = 0               # leading dense layers (MoE family)
+    dense_d_ff: int = 0                   # ... and their MLP width
+    experts_held: tuple = ()              # [lo, hi): this chip's experts
     # --- SSM / hybrid ---
     ssm_state: int = 0
     ssm_head_dim: int = 64
@@ -62,6 +74,21 @@ class ModelConfig:
     @property
     def hd(self) -> int:
         return self.head_dim or self.d_model // self.n_heads
+
+    @property
+    def mla(self) -> bool:
+        return self.kv_lora_rank > 0
+
+    @property
+    def expert_range(self) -> tuple[int, int]:
+        """The routed experts whose weights this configuration holds: all
+        of them, or the share one chip of an expert-parallel layer holds."""
+        return tuple(self.experts_held) or (0, self.n_experts)
+
+    @property
+    def n_held_experts(self) -> int:
+        lo, hi = self.expert_range
+        return hi - lo
 
     @property
     def padded_vocab(self) -> int:
@@ -92,6 +119,13 @@ class ModelConfig:
             vocab_size=512,
             n_experts=min(self.n_experts, 4),
             top_k=min(self.top_k, 2),
+            experts_held=(),
+            kv_lora_rank=min(self.kv_lora_rank, 32),
+            qk_nope_head_dim=min(self.qk_nope_head_dim, 16),
+            qk_rope_head_dim=min(self.qk_rope_head_dim, 8),
+            v_head_dim=min(self.v_head_dim, 16),
+            shared_d_ff=min(self.shared_d_ff, 128),
+            dense_d_ff=min(self.dense_d_ff, 512),
             ssm_state=min(self.ssm_state, 16) if self.ssm_state else 0,
             ssm_head_dim=32,
             shared_attn_period=2 if self.shared_attn_period else 0,
@@ -105,6 +139,8 @@ class ModelConfig:
         D, H, KV, hd, F, V = (self.d_model, self.n_heads, self.n_kv_heads,
                               self.hd, self.d_ff, self.vocab_size)
         def attn(bias: bool) -> int:
+            if self.mla:
+                return self._mla_params()
             n = D * H * hd + 2 * D * KV * hd + H * hd * D
             if bias:
                 n += H * hd + 2 * KV * hd
@@ -117,10 +153,16 @@ class ModelConfig:
             return self.n_layers * per + emb + D
         if self.family == Family.MOE:
             per = attn(self.qkv_bias) + 2 * D + D * self.n_experts \
-                + self.n_experts * mlp(F)
+                + self.n_held_experts * mlp(F)
+            if self.router == "sigmoid_bias":
+                per += self.n_experts             # the correction bias
+            if self.shared_d_ff:
+                per += mlp(self.shared_d_ff)
             if self.dense_residual:
                 per += mlp(F)
-            return self.n_layers * per + emb + D
+            dense = attn(self.qkv_bias) + mlp(self.dense_d_ff) + 2 * D
+            return ((self.n_layers - self.n_dense_layers) * per
+                    + self.n_dense_layers * dense + emb + D)
         if self.family == Family.SSM:  # rwkv6
             per = self._rwkv6_layer_params()
             return self.n_layers * per + emb + D
@@ -133,6 +175,15 @@ class ModelConfig:
             dec = self.n_layers * (2 * attn(False) + mlp(F) + 3 * D)
             return enc + dec + emb + 2 * D   # enc_norm + final_norm
         raise ValueError(self.family)
+
+    def _mla_params(self) -> int:
+        D, H, R = self.d_model, self.n_heads, self.kv_lora_rank
+        nope, rope, v = (self.qk_nope_head_dim, self.qk_rope_head_dim,
+                         self.v_head_dim)
+        return (D * H * (nope + rope)            # wq
+                + D * (R + rope) + R             # wkv_a, kv_norm
+                + R * H * (nope + v)             # wk_b, wv_b
+                + H * v * D)                     # wo
 
     def _mamba2_layer_params(self) -> int:
         D, Din, S = self.d_model, self.d_inner, self.ssm_state
@@ -158,13 +209,14 @@ class ModelConfig:
         return tm + cm + ln
 
     def active_param_count(self) -> int:
-        """6*N_active*D basis for MODEL_FLOPS (MoE: top_k of n_experts)."""
+        """6*N_active*D basis for MODEL_FLOPS (MoE: top_k routed experts
+        a token in each expert layer, in place of those held)."""
         if self.family != Family.MOE:
             return self.param_count()
-        full = self.param_count()
         expert = 3 * self.d_model * self.d_ff
-        inactive = self.n_layers * (self.n_experts - self.top_k) * expert
-        return full - inactive
+        moe_layers = self.n_layers - self.n_dense_layers
+        return self.param_count() - moe_layers * (
+            self.n_held_experts - self.top_k) * expert
 
 
 class ShapeKind(str, Enum):

@@ -9,7 +9,7 @@ ARCHS = {
     "llava-next-34b": "llava_next_34b",
     "zamba2-2.7b": "zamba2_2p7b",
     "seamless-m4t-medium": "seamless_m4t_medium",
-    "moonshot-v1-16b-a3b": "moonshot_v1_16b_a3b",
+    "moonlight-16b-a3b": "moonlight_16b_a3b",
     "arctic-480b": "arctic_480b",
     "rwkv6-3b": "rwkv6_3b",
 }

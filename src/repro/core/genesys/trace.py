@@ -76,6 +76,12 @@ A request's milestones in the continuous engine (``sysno`` =
     FIRST_TOKEN the engine appended its first generated token (ts = when
                 the host had it, aux = the engine step's index)
 
+An MoE engine step adds, per expert layer (channel as STEP's):
+
+    MOE_ROUTE   sysno = the step's index, seq = the layer, ts = the step
+                start, aux = the held experts the seated rows hit, plus
+                (row, held expert) assignments << ``MOE_ROUTE_SHIFT``
+
 The serving loop (``serve_model_continuous``, channel ``loop``): every
 iteration is recorded by a :class:`LoopRecorder` as one ``LOOP`` event
 per phase it ran, in one staged block:
@@ -131,6 +137,9 @@ EV_STEP = 13
 EV_LOOP = 14
 EV_ADMIT = 15
 EV_FIRST_TOKEN = 16
+EV_MOE_ROUTE = 17
+# an EV_MOE_ROUTE event's aux: held experts hit + (assignments << shift)
+MOE_ROUTE_SHIFT = 16
 
 EV_NAMES = {
     EV_SUBMIT: "SUBMIT", EV_SQ_POP: "SQ_POP", EV_FUSE_MERGE: "FUSE_MERGE",
@@ -138,7 +147,7 @@ EV_NAMES = {
     EV_IRQ: "IRQ", EV_FALLBACK: "FALLBACK", EV_THROTTLE: "THROTTLE",
     EV_REJECT: "REJECT", EV_REQ_BEGIN: "REQ_BEGIN", EV_REQ_END: "REQ_END",
     EV_STEP: "STEP", EV_LOOP: "LOOP", EV_ADMIT: "ADMIT",
-    EV_FIRST_TOKEN: "FIRST_TOKEN",
+    EV_FIRST_TOKEN: "FIRST_TOKEN", EV_MOE_ROUTE: "MOE_ROUTE",
 }
 
 # serving-loop phases, in the order one iteration runs them: the code

@@ -367,6 +367,143 @@ def attention(p, cfg, rules, x, *, positions, causal=True, kv_x=None,
     return constrain(y, rules, "batch", "seq", "embed"), new_cache
 
 
+# --------------------------------------------------- latent attention -----
+
+def init_mla(pb, cfg, prefix: str = "attn"):
+    """DeepSeek-V3 latent attention without query compression
+    (``q_lora_rank`` null): ``kv_b_proj`` is kept as its key half ``wk_b``
+    and its value half ``wv_b``, so decode can absorb them."""
+    D, H, R = cfg.d_model, cfg.n_heads, cfg.kv_lora_rank
+    nope, rope_d, vd = (cfg.qk_nope_head_dim, cfg.qk_rope_head_dim,
+                        cfg.v_head_dim)
+    a = pb.sub(prefix)
+    a.param("wq", (D, H, nope + rope_d), ("embed", "heads", "head_dim"))
+    a.param("wkv_a", (D, R + rope_d), ("embed", "kv_lora"))
+    a.param("kv_norm", (R,), ("kv_lora",), init="ones")
+    a.param("wk_b", (R, H, nope), ("kv_lora", "heads", "head_dim"))
+    a.param("wv_b", (R, H, vd), ("kv_lora", "heads", "head_dim"))
+    a.param("wo", (H, vd, D), ("heads", "head_dim", "embed"))
+
+
+def mla_entries(p, cfg, x, positions):
+    """Each token's cache entries, shared by every head: ``latent``
+    [..., kv_lora_rank], the RMS-normed latent, and ``rope``
+    [..., qk_rope_head_dim], the rotated key. Two arrays, not one of
+    their 576-wide concatenation: a minor dimension that is no multiple
+    of 128 gives the TPU's arena another layout than the step computes
+    in, and every step then converts the whole arena twice."""
+    dt = x.dtype
+    R = cfg.kv_lora_rank
+    kv = jnp.einsum("bsd,dr->bsr", x, p["wkv_a"].astype(dt))
+    c = rmsnorm(kv[..., :R], p["kv_norm"], cfg.norm_eps)
+    k_pe = rope(kv[..., None, R:], positions, cfg.rope_theta)[..., 0, :]
+    return {"latent": c, "rope": k_pe}
+
+
+def mla_absorbed(p, cfg, q_nope, q_pe, kv, mask):
+    """Scores over the latent itself: the queries absorb ``wk_b`` and the
+    latent-weighted sum leaves through ``wv_b`` (decode). q_nope
+    [B,S,H,nope], q_pe [B,S,H,rope]; kv {latent [B,T,R], rope
+    [B,T,rope]}; mask [B,S,T] -> [B,S,H,v]."""
+    dt = q_nope.dtype
+    f32 = jnp.float32
+    lat = kv["latent"]
+    q_lat = jnp.einsum("bshn,rhn->bshr", q_nope, p["wk_b"].astype(dt),
+                       preferred_element_type=f32).astype(dt)
+    s = (jnp.einsum("bshr,btr->bhst", q_lat, lat,
+                    preferred_element_type=f32)
+         + jnp.einsum("bshk,btk->bhst", q_pe, kv["rope"],
+                      preferred_element_type=f32))
+    pr = _masked_softmax(s, mask, cfg)
+    o = jnp.einsum("bhst,btr->bhsr", pr.astype(dt), lat,
+                   preferred_element_type=f32).astype(dt)
+    return jnp.einsum("bhsr,rhv->bshv", o, p["wv_b"].astype(dt),
+                      preferred_element_type=f32).astype(dt)
+
+
+def mla_expanded(p, cfg, q_nope, q_pe, kv, mask):
+    """The published form: every position's keys and values expanded from
+    its latent through ``wk_b``/``wv_b`` (training, prompt chunks). Same
+    shapes as :func:`mla_absorbed`."""
+    dt = q_nope.dtype
+    f32 = jnp.float32
+    k = jnp.einsum("btr,rhn->bthn", kv["latent"], p["wk_b"].astype(dt))
+    v = jnp.einsum("btr,rhv->bthv", kv["latent"], p["wv_b"].astype(dt))
+    s = (jnp.einsum("bshn,bthn->bhst", q_nope, k, preferred_element_type=f32)
+         + jnp.einsum("bshk,btk->bhst", q_pe, kv["rope"],
+                      preferred_element_type=f32))
+    pr = _masked_softmax(s, mask, cfg)
+    return jnp.einsum("bhst,bthv->bshv", pr.astype(dt), v,
+                      preferred_element_type=f32).astype(dt)
+
+
+def _masked_softmax(s, mask, cfg):
+    scale = (cfg.qk_nope_head_dim + cfg.qk_rope_head_dim) ** -0.5
+    s = jnp.where(mask[:, None], s * scale, -jnp.inf)
+    return jax.nn.softmax(s, axis=-1)
+
+
+def mla_attention(p, cfg, rules, x, *, positions, n_valid=None,
+                  entries=None, block_tables=None, li=None):
+    """Latent attention -> ``(out, entries')``. Without ``entries``,
+    causal over the S tokens themselves (``entries'`` None). With them,
+    each query attends over its row's entries of layer ``li`` up to its
+    own position, the S new entries among them, which are then written
+    at their slots: ``entries`` is :func:`mla_entries`' pair as paged
+    arenas [L,NB,BS,n] addressed through ``block_tables`` [B,MB], or,
+    without tables, a carried cache [L,B,Smax,n]. Padding tokens
+    (i >= n_valid) and positions past the cache are aimed out of range,
+    so dropped; inactive rows write into the null block, as in the paged
+    GQA branch of :func:`attention`. S == 1 (decode) runs the absorbed
+    form, S > 1 the expanded one."""
+    dt = x.dtype
+    B, S = x.shape[:2]
+    nope = cfg.qk_nope_head_dim
+    q = jnp.einsum("bsd,dhk->bshk", x, p["wq"].astype(dt))
+    q = constrain(q, rules, "batch", "seq", "heads", "head_dim")
+    q_nope, q_pe = q[..., :nope], rope(q[..., nope:], positions,
+                                       cfg.rope_theta)
+    new = mla_entries(p, cfg, x, positions)                # {name: [B,S,n]}
+    if entries is None:
+        kv, kpos = new, positions[:, None, :]
+    else:
+        NB, BS = entries["latent"].shape[1:3]
+        n_flat = NB * BS
+        if block_tables is not None:
+            bt = block_tables
+            blk = jnp.take_along_axis(
+                bt, jnp.minimum(positions // BS, bt.shape[1] - 1), axis=1)
+            slot = blk * BS + positions % BS
+        else:
+            slot = jnp.where(positions < BS,
+                             jnp.arange(B)[:, None] * BS + positions, n_flat)
+        if n_valid is not None:
+            i = jnp.arange(S, dtype=jnp.int32)
+            slot = jnp.where(i[None] < n_valid[:, None], slot, n_flat)
+        kv, out = {}, {}
+        for name, arena in entries.items():
+            a_l = jax.lax.dynamic_index_in_dim(arena, li, 0, keepdims=False)
+            n = a_l.shape[-1]
+            view = a_l if block_tables is None else \
+                a_l[block_tables].reshape(B, -1, n)
+            e = new[name].astype(a_l.dtype)
+            # the new entries join the row's view at their positions (a
+            # padding token's lies past every real query, so none sees it)
+            kv[name] = view.at[jnp.arange(B)[:, None], positions].set(
+                e, mode="drop").astype(dt)
+            flat = a_l.reshape(-1, n).at[slot.reshape(-1)].set(
+                e.reshape(-1, n), mode="drop")
+            out[name] = jax.lax.dynamic_update_slice_in_dim(
+                arena, flat.reshape(a_l.shape)[None], li, axis=0)
+        entries = out
+        kpos = jnp.arange(kv["latent"].shape[1], dtype=jnp.int32)[None, None]
+    mask = kpos <= positions[:, :, None]                   # [B,S,T]
+    form = mla_absorbed if S == 1 and entries is not None else mla_expanded
+    o = form(p, cfg, q_nope, q_pe, kv, mask)
+    y = jnp.einsum("bshv,hvd->bsd", o, p["wo"].astype(dt))
+    return constrain(y, rules, "batch", "seq", "embed"), entries
+
+
 # ------------------------------------------------------------------ mlp -----
 
 def init_mlp(pb, cfg, d_ff=None, prefix: str = "mlp"):

@@ -15,20 +15,40 @@ from repro.models import moe as MOE
 from repro.sharding import constrain
 
 
+DENSE_STACK = 0x0DE5E     # the leading dense layers' key, apart from blocks
+
+
 def init(rng, cfg: ModelConfig):
     pb = ParamBuilder(rng, jnp.dtype(cfg.params_dtype))
     pb.param("embed", (cfg.padded_vocab, cfg.d_model), ("vocab", "embed"),
              scale=1.0)
+
+    def attn_and_norms(lpb: ParamBuilder):
+        if cfg.mla:
+            L.init_mla(lpb, cfg)
+        else:
+            L.init_attention(lpb, cfg)
+        lpb.param("ln_attn", (cfg.d_model,), ("embed",), init="ones")
+        lpb.param("ln_mlp", (cfg.d_model,), ("embed",), init="ones")
+
     def one(lpb: ParamBuilder, i: int):
-        L.init_attention(lpb, cfg)
+        attn_and_norms(lpb)
         if cfg.family == Family.MOE:
             MOE.init_moe(lpb, cfg)
         else:
             L.init_mlp(lpb, cfg)
-        lpb.param("ln_attn", (cfg.d_model,), ("embed",), init="ones")
-        lpb.param("ln_mlp", (cfg.d_model,), ("embed",), init="ones")
-    blocks, blocks_axes = stack_layers(rng, jnp.dtype(cfg.params_dtype),
-                                       cfg.n_layers, one)
+
+    def dense(lpb: ParamBuilder, i: int):
+        attn_and_norms(lpb)
+        L.init_mlp(lpb, cfg, d_ff=cfg.dense_d_ff)
+
+    dt = jnp.dtype(cfg.params_dtype)
+    if cfg.n_dense_layers:
+        pb.params["dense_blocks"], pb.axes["dense_blocks"] = stack_layers(
+            jax.random.fold_in(rng, DENSE_STACK), dt, cfg.n_dense_layers,
+            dense)
+    blocks, blocks_axes = stack_layers(
+        rng, dt, cfg.n_layers - cfg.n_dense_layers, one)
     pb.params["blocks"] = blocks
     pb.axes["blocks"] = blocks_axes
     pb.param("final_norm", (cfg.d_model,), ("embed",), init="ones")
@@ -37,19 +57,43 @@ def init(rng, cfg: ModelConfig):
     return pb.params, pb.axes
 
 
-def _block(cfg, rules, p, x, *, positions, cache=None, cache_len=None,
-           carried_cache=None, paged_cache=None, n_valid=None):
-    h, new_cache = L.attention(
-        p["attn"], cfg, rules, L.rmsnorm(x, p["ln_attn"]),
-        positions=positions, cache=cache, cache_len=cache_len,
-        carried_cache=carried_cache, paged_cache=paged_cache,
-        n_valid=n_valid)
-    x = x + h
-    if cfg.family == Family.MOE:
-        x = x + MOE.moe_mlp(p, cfg, rules, L.rmsnorm(x, p["ln_mlp"]))
+def _stacks(params, cfg):
+    """Each scanned stack of layers and the index of its first layer: the
+    leading dense layers (MoE family), then the uniform ``blocks``."""
+    out = [(params["dense_blocks"], 0)] if cfg.n_dense_layers else []
+    return out + [(params["blocks"], cfg.n_dense_layers)]
+
+
+def _block(cfg, rules, p, x, *, positions, cache_len=None, entries=None,
+           block_tables=None, li=None, n_valid=None, moe_rows=None):
+    """One layer. ``entries`` is the family's cache (:func:`init_cache`'s
+    or :func:`init_paged_arena`'s dict), paged when ``block_tables`` is
+    given; ``li`` its layer. -> (x, entries', hits or None)."""
+    h = L.rmsnorm(x, p["ln_attn"], cfg.norm_eps)
+    if cfg.mla:
+        h, entries = L.mla_attention(
+            p["attn"], cfg, rules, h, positions=positions, n_valid=n_valid,
+            entries=entries, block_tables=block_tables, li=li)
     else:
-        x = x + L.mlp(p["mlp"], rules, L.rmsnorm(x, p["ln_mlp"]))
-    return x, new_cache
+        carried = paged = None
+        if entries is not None and block_tables is not None:
+            paged = (entries["k"], entries["v"], block_tables, li)
+        elif entries is not None:
+            carried = (entries["k"], entries["v"], li)
+        h, kv = L.attention(
+            p["attn"], cfg, rules, h, positions=positions,
+            cache_len=cache_len, carried_cache=carried, paged_cache=paged,
+            n_valid=n_valid)
+        entries = None if kv is None else {"k": kv[0], "v": kv[1]}
+    x = x + h
+    hits = None
+    h = L.rmsnorm(x, p["ln_mlp"], cfg.norm_eps)
+    if "moe" in p:
+        y, hits = MOE.moe_mlp(p, cfg, rules, h, dropless=entries is not None,
+                              rows=moe_rows)
+    else:
+        y = L.mlp(p["mlp"], rules, h)
+    return x + y, entries, hits
 
 
 def _remat(fn, cfg):
@@ -63,13 +107,16 @@ def _remat(fn, cfg):
 
 def forward(params, cfg: ModelConfig, rules, tokens, *, embeds=None,
             positions=None, cache=None, cache_len=None, paged_cache=None,
-            n_valid=None):
+            n_valid=None, moe_rows=None):
     """tokens: [B,S] int32. embeds: [B,P,D] precomputed prefix (VLM stub).
-    cache: stacked {k,v: [L,B,S,KV,hd]} for decode. paged_cache:
-    (k_pages, v_pages, block_tables) with arenas [L,NB,BS,KV,hd] shared by
-    all sequences and per-row block tables [B,MB] + cache_len [B]
-    (genesys.pagedkv continuous batching); with S > 1, n_valid [B] counts
-    each row's real tokens. Returns (logits, cache').
+    cache: the carried stacked cache (:func:`init_cache`) for decode.
+    paged_cache: (arenas, block_tables): the family's arenas
+    (:func:`init_paged_arena`) shared by all sequences and per-row block
+    tables [B,MB] + cache_len [B] (genesys.pagedkv continuous batching);
+    with S > 1, n_valid [B] counts each row's real tokens. Returns
+    (logits, cache'); with ``moe_rows`` [B] bool (a cached MoE forward)
+    also the expert layers' hits [MoE layers, held experts] int32 over
+    those rows' tokens.
     """
     dt = jnp.dtype(cfg.compute_dtype)
     x = params["embed"].astype(dt)[tokens]
@@ -84,68 +131,79 @@ def forward(params, cfg: ModelConfig, rules, tokens, *, embeds=None,
                                          (B, S))
     x = constrain(x, rules, "batch", "seq", "embed")
 
-    if paged_cache is not None:
-        # paged decode: every layer reads/writes its slice of the shared
-        # block arenas through the SAME per-sequence block table (a block
-        # id is valid at every layer — one table per sequence, not per
-        # layer), carried through the scan like the dense stacked cache
-        kp0, vp0, bt = paged_cache
-
-        def body(carry, z):
-            h, kp, vp = carry
-            h, (kp, vp) = _block(cfg, rules, z["p"], h, positions=positions,
-                                 paged_cache=(kp, vp, bt, z["i"]),
-                                 cache_len=cache_len, n_valid=n_valid)
-            return (h, kp, vp), None
-        xs = {"p": params["blocks"],
-              "i": jnp.arange(cfg.n_layers, dtype=jnp.int32)}
-        (x, kp, vp), _ = jax.lax.scan(body, (x, kp0, vp0), xs)
-        new_cache = {"k": kp, "v": vp}
-    elif cache is not None:
-        # carried stacked cache: in-place single-token updates (§Perf)
-        def body(carry, z):
-            h, kc, vc = carry
-            h, (kc, vc) = _block(cfg, rules, z["p"], h, positions=positions,
-                                 carried_cache=(kc, vc, z["i"]),
-                                 cache_len=cache_len)
-            return (h, kc, vc), None
-        xs = {"p": params["blocks"],
-              "i": jnp.arange(cfg.n_layers, dtype=jnp.int32)}
-        (x, kc, vc), _ = jax.lax.scan(body, (x, cache["k"], cache["v"]), xs)
-        new_cache = {"k": kc, "v": vc}
+    new_cache = hits = None
+    if paged_cache is not None or cache is not None:
+        # every layer reads/writes its slice of the stacked cache, carried
+        # through the scan; paged, through the SAME per-sequence block
+        # table (a block id is valid at every layer). Every stack indexes
+        # the one cache by its layers' own indices.
+        entries, bt = paged_cache if paged_cache is not None else (cache,
+                                                                   None)
+        for stack, first in _stacks(params, cfg):
+            def body(carry, z):
+                h, ent = carry
+                h, ent, hit = _block(cfg, rules, z["p"], h,
+                                     positions=positions, cache_len=cache_len,
+                                     entries=ent, block_tables=bt, li=z["i"],
+                                     n_valid=n_valid, moe_rows=moe_rows)
+                return (h, ent), hit
+            n = jax.tree_util.tree_leaves(stack)[0].shape[0]
+            xs = {"p": stack, "i": first + jnp.arange(n, dtype=jnp.int32)}
+            (x, entries), hit = jax.lax.scan(body, (x, entries), xs)
+            hits = hit if hit is not None else hits
+        new_cache = entries
     else:
         def body(h, layer):
-            h, _ = _block(cfg, rules, layer, h, positions=positions)
+            h, _, _ = _block(cfg, rules, layer, h, positions=positions)
             return h, None
         body = _remat(body, cfg)
-        x, _ = jax.lax.scan(body, x, params["blocks"])
-        new_cache = None
+        for stack, _ in _stacks(params, cfg):
+            x, _ = jax.lax.scan(body, x, stack)
 
-    x = L.rmsnorm(x, params["final_norm"])
+    x = L.rmsnorm(x, params["final_norm"], cfg.norm_eps)
     head = (params["embed"].T if cfg.tie_embeddings else params["lm_head"])
     logits = jnp.einsum("bsd,dv->bsv", x, head.astype(dt))
     logits = constrain(logits, rules, "batch", "seq", "vocab")
+    if moe_rows is not None:
+        return logits.astype(jnp.float32), new_cache, hits
     return logits.astype(jnp.float32), new_cache
+
+
+def _entry_shapes(cfg: ModelConfig, kv_rep: int = 1) -> dict:
+    """The family's cache entries, each per token and layer: one K and
+    one V of [KV, hd], or MLA's latent [kv_lora_rank] and rotated key
+    [qk_rope_head_dim] (:func:`layers.mla_entries`)."""
+    if cfg.mla:
+        return {"latent": (cfg.kv_lora_rank,),
+                "rope": (cfg.qk_rope_head_dim,)}
+    one = (cfg.n_kv_heads * kv_rep, cfg.hd)
+    return {"k": one, "v": one}
 
 
 def init_cache(cfg: ModelConfig, batch: int, max_len: int, dtype=None,
                kv_rep: int = 1):
+    """The carried decode cache: {entry: [L, B, S, ...]}."""
     dtype = dtype or jnp.dtype(cfg.kv_cache_dtype)
-    shape = (cfg.n_layers, batch, max_len, cfg.n_kv_heads * kv_rep, cfg.hd)
-    return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
+    return {name: jnp.zeros((cfg.n_layers, batch, max_len) + shape, dtype)
+            for name, shape in _entry_shapes(cfg, kv_rep).items()}
 
 
 def init_paged_arena(cfg: ModelConfig, n_blocks: int, block_size: int,
                      dtype=None, kv_rep: int = 1):
-    """Block arenas for paged decode: {k,v: [L, NB, BS, KV, hd]}. One
-    arena serves every concurrent sequence; block 0 is the pool's null
-    block (padding target for inactive rows / short tables)."""
+    """Block arenas for paged decode: {entry: [L, NB, BS, ...]}, the
+    family's entries ({k,v: [L, NB, BS, KV, hd]}, or MLA's {latent:
+    [L, NB, BS, kv_lora_rank], rope: [L, NB, BS, qk_rope_head_dim]}).
+    One arena serves every concurrent sequence; block 0 is the pool's
+    null block (padding target for inactive rows / short tables)."""
     dtype = dtype or jnp.dtype(cfg.kv_cache_dtype)
-    shape = (cfg.n_layers, n_blocks, block_size,
-             cfg.n_kv_heads * kv_rep, cfg.hd)
-    return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
+    return {name: jnp.zeros((cfg.n_layers, n_blocks, block_size) + shape,
+                            dtype)
+            for name, shape in _entry_shapes(cfg, kv_rep).items()}
 
 
 def cache_axes(cfg: ModelConfig):
+    if cfg.mla:
+        ax = ("stack", "batch", "seq", "kv_lora")
+        return {"latent": ax, "rope": ax}
     ax = ("stack", "batch", "seq", "kv_heads", "kv_head_dim")
     return {"k": ax, "v": ax}

@@ -38,12 +38,14 @@ import time
 from collections import deque
 from dataclasses import dataclass, field
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
 from repro.core.genesys.trace import (
-    EV_ADMIT, EV_FIRST_TOKEN, EV_STEP, LOOP_ADVANCE, LOOP_DISPATCH,
-    LOOP_RETIRE, LOOP_SYNC, REQ_SYSNO, Counters,
+    EV_ADMIT, EV_FIRST_TOKEN, EV_MOE_ROUTE, EV_STEP, LOOP_ADVANCE,
+    LOOP_DISPATCH, LOOP_RETIRE, LOOP_SYNC, MOE_ROUTE_SHIFT, REQ_SYSNO,
+    Counters,
 )
 from repro.serving.pagedkv import NULL_BLOCK, PagedKVPool, PoolExhausted
 
@@ -86,14 +88,15 @@ class ContinuousBatchEngine:
         self.serve_step = serve_step
         self.prefill_chunk = prefill_chunk
         self.params = params
-        self.arenas = arenas          # {k,v: [L,NB,BS,KV,hd]}
+        # the family's entries, each [L,NB,BS,...] (init_paged_arena)
+        self.arenas = arenas
         self.pool = pool
         self.n_slots = int(n_slots)
         self.max_blocks = int(max_blocks_per_seq)
         self.block_size = pool.block_size
-        if arenas["k"].shape[1] != pool.n_blocks:
+        if any(a.shape[1] != pool.n_blocks for a in arenas.values()):
             raise ValueError("arena/pool block-count mismatch")
-        if arenas["k"].shape[2] != pool.block_size:
+        if any(a.shape[2] != pool.block_size for a in arenas.values()):
             raise ValueError("arena/pool block-size mismatch")
         # every chunk starts on a block boundary (a reused prefix is whole
         # blocks), so no chunk writes into a shared sealed block
@@ -146,24 +149,25 @@ class ContinuousBatchEngine:
         return self.n_slots - self.n_active
 
     # --------------------------------------------------- arena <-> bytes ----
+    # a block's bytes: each entry's [L, BS, ...] slice, in key order
     def block_bytes(self) -> int:
-        k = self.arenas["k"]
-        return 2 * int(np.prod(k.shape)) // k.shape[1] * k.dtype.itemsize
+        return sum(int(np.prod(a.shape)) // a.shape[1] * a.dtype.itemsize
+                   for a in self.arenas.values())
 
     def _extract_block(self, bid: int) -> bytes:
-        k = np.asarray(self.arenas["k"][:, bid])
-        v = np.asarray(self.arenas["v"][:, bid])
-        return k.tobytes() + v.tobytes()
+        return b"".join(np.asarray(self.arenas[name][:, bid]).tobytes()
+                        for name in sorted(self.arenas))
 
     def _install_block(self, bid: int, payload: bytes) -> None:
-        k = self.arenas["k"]
-        shape = (k.shape[0],) + k.shape[2:]
-        half = len(payload) // 2
-        dt = np.dtype(k.dtype)
-        kb = np.frombuffer(payload[:half], dtype=dt).reshape(shape)
-        vb = np.frombuffer(payload[half:], dtype=dt).reshape(shape)
-        self.arenas["k"] = self.arenas["k"].at[:, bid].set(jnp.asarray(kb))
-        self.arenas["v"] = self.arenas["v"].at[:, bid].set(jnp.asarray(vb))
+        at = 0
+        for name in sorted(self.arenas):
+            a = self.arenas[name]
+            shape = (a.shape[0],) + a.shape[2:]
+            dt = np.dtype(a.dtype)
+            n = int(np.prod(shape)) * dt.itemsize
+            b = np.frombuffer(payload[at:at + n], dtype=dt).reshape(shape)
+            self.arenas[name] = a.at[:, bid].set(jnp.asarray(b))
+            at += n
 
     # ----------------------------------------------------------- admission --
     def admit(self, prompt, n_tokens: int, meta=None,
@@ -289,12 +293,16 @@ class ContinuousBatchEngine:
         if not active:
             return []
         t0 = time.perf_counter_ns()
-        nxt, self.arenas = self.serve_step(
+        # an MoE step also returns the expert layers' hits
+        nxt, self.arenas, *hits = self.serve_step(
             self.params, self.arenas, jnp.asarray(self._bt),
             jnp.asarray(self._cur), jnp.asarray(self._cl))
         if loop is not None:
             loop.phase(LOOP_SYNC)
-        nxt = np.asarray(nxt)
+        if hits:
+            nxt, hits = jax.device_get((nxt, hits[0]))
+        else:
+            nxt, hits = np.asarray(nxt), None
         dur = time.perf_counter_ns() - t0
         if loop is not None:
             loop.phase(LOOP_ADVANCE)
@@ -336,12 +344,26 @@ class ContinuousBatchEngine:
             if firsts:
                 ch.rec_block(EV_FIRST_TOKEN, REQ_SYSNO, firsts,
                              aux=self._step_idx, ts=t0 + dur, own=True)
+            if hits is not None:
+                self._record_routes(ch, hits, t0)
         self._step_idx += 1
         if loop is not None:
             loop.phase(LOOP_RETIRE)
         for i, st in done:
             self._retire(i, st)
         return [(st.meta, st.gen) for _, st in done]
+
+    def _record_routes(self, ch, hits: np.ndarray, ts: int) -> None:
+        """One ``EV_MOE_ROUTE`` per expert layer: the step in ``sysno``,
+        the layer in ``seq``, and in ``aux`` the held experts hit plus the
+        (row, held expert) assignments shifted by ``MOE_ROUTE_SHIFT``."""
+        hits = hits.astype(np.int64)
+        n_layers = next(iter(self.arenas.values())).shape[0]
+        aux = (hits > 0).sum(1) + (hits.sum(1) << MOE_ROUTE_SHIFT)
+        ch.rec_block(EV_MOE_ROUTE, self._step_idx,
+                     np.arange(n_layers - len(hits), n_layers,
+                               dtype=np.int64),
+                     aux=aux, ts=ts, own=True)
 
     def drain(self) -> list[tuple[object, list[int]]]:
         """Run steps until every active request has retired."""

@@ -5,8 +5,9 @@ per-request contiguous buffers (vLLM's paged attention, reproduced over
 the genesys memory stack):
 
   * one arena of ``n_blocks`` blocks of ``block_size`` token positions
-    (the device side lives in ``models.transformer.init_paged_arena``
-    arenas [L, NB, BS, KV, hd]; this class is the host-side allocator);
+    (the device side lives in ``models.transformer.init_paged_arena``:
+    the family's entries, each [L, NB, BS, ...], K and V, or MLA's
+    latent and rotated key; this class is the host-side allocator);
   * per-request **block tables** map a sequence's logical block index to
     an arena block id — the Pallas split-KV kernel and the XLA reference
     both read K/V through the table, so sequences are never copied or

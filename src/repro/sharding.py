@@ -53,6 +53,7 @@ class ShardingRules:
         embed2=None,          # square D->D projections, output side
         experts="model",
         expert_mlp=None,
+        kv_lora=None,         # MLA latent width: never split (replicated)
         ssm_inner="model",
         ssm_state=None,
         conv=None,

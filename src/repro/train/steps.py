@@ -16,6 +16,7 @@ import jax.numpy as jnp
 from repro.config import ModelConfig, Family, TrainConfig
 from repro.models.registry import get_api
 from repro.optim import AdamW
+from repro.serving.pagedkv import NULL_BLOCK
 
 IGNORE = -100
 
@@ -118,45 +119,50 @@ def _paged_api(cfg: ModelConfig):
 
 def make_paged_serve_step(cfg: ModelConfig, rules):
     """One paged decode step over the genesys.pagedkv arena:
-    (params, arenas {k,v: [L,NB,BS,KV,hd]}, block_tables [B,MB],
-    token [B,1], cache_len [B]) -> (next_token [B], new_arenas).
+    (params, arenas, block_tables [B,MB], token [B,1], cache_len [B]) ->
+    (next_token [B], new_arenas), where ``arenas`` holds the family's
+    entries (``transformer.init_paged_arena``: {k,v: [L,NB,BS,KV,hd]} for
+    GQA, {latent: [L,NB,BS,R], rope: [L,NB,BS,r]} for MLA). An MoE step
+    also returns ``hits`` [MoE layers, held experts] int32: how many
+    seated rows (a row whose block table is not all null) chose each held
+    expert.
 
     The batch shape is the engine's FIXED slot count — admitting or
     retiring a request changes only block_tables/cache_len row contents,
     never an array shape, so membership churn cannot trigger a re-jit.
     """
     api = _paged_api(cfg)
+    moe = cfg.family == Family.MOE
 
     def paged_serve_step(params, arenas, block_tables, token, cache_len):
-        logits, new_arenas = api.forward(
-            params, cfg, rules, token,
-            paged_cache=(arenas["k"], arenas["v"], block_tables),
-            cache_len=cache_len)
+        kw = {"moe_rows": block_tables[:, 0] != NULL_BLOCK} if moe else {}
+        logits, new_arenas, *hits = api.forward(
+            params, cfg, rules, token, paged_cache=(arenas, block_tables),
+            cache_len=cache_len, **kw)
         next_tok = jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32)
-        return next_tok, new_arenas
+        return (next_tok, new_arenas, *hits)
 
     return paged_serve_step
 
 
 def make_paged_prefill_chunk(cfg: ModelConfig, rules):
-    """Write one prompt chunk's KV into the genesys.pagedkv arena:
-    (params, arenas {k,v: [L,NB,BS,KV,hd]}, block_row [1,MB],
-    tokens [1,C], start [1], n_valid [1]) -> new_arenas.
+    """Write one prompt chunk's entries into the genesys.pagedkv arena:
+    (params, arenas (the family's, as the decode step's), block_row
+    [1,MB], tokens [1,C], start [1], n_valid [1]) -> new_arenas.
 
     The C tokens sit at positions start + arange(C) of the row; the first
     n_valid are the prompt's, the rest padding whose writes are dropped.
-    Each attends causally over the row's pages, so the KV it leaves equals
-    what feeding the tokens one decode step at a time writes. It returns
-    no logits: under jit the unused final norm and output head are dead
-    code. One fixed shape, so the engine compiles it once.
+    Each attends causally over the row's pages, so the entries it leaves
+    equal what feeding the tokens one decode step at a time writes. It
+    returns no logits: under jit the unused final norm and output head
+    are dead code. One fixed shape, so the engine compiles it once.
     """
     api = _paged_api(cfg)
 
     def paged_prefill_chunk(params, arenas, block_row, tokens, start,
                             n_valid):
         _, new_arenas = api.forward(
-            params, cfg, rules, tokens,
-            paged_cache=(arenas["k"], arenas["v"], block_row),
+            params, cfg, rules, tokens, paged_cache=(arenas, block_row),
             cache_len=start, n_valid=n_valid)
         return new_arenas
 

@@ -66,14 +66,24 @@ def test_param_count_formula(arch):
 
 
 @pytest.mark.parametrize("arch", ["qwen2-72b", "arctic-480b", "rwkv6-3b",
-                                  "zamba2-2.7b"])
+                                  "zamba2-2.7b", "moonlight-16b-a3b"])
 def test_full_config_param_counts_sane(arch):
     """Full-size configs land near their nameplate parameter counts."""
     cfg = get_config(arch)
     n = cfg.param_count()
     nameplate = {"qwen2-72b": 72e9, "arctic-480b": 480e9,
-                 "rwkv6-3b": 3e9, "zamba2-2.7b": 2.7e9}[arch]
+                 "rwkv6-3b": 3e9, "zamba2-2.7b": 2.7e9,
+                 "moonlight-16b-a3b": 16e9}[arch]
     assert 0.7 * nameplate < n < 1.45 * nameplate, (arch, n)
+
+
+def test_moonlight_nameplate_total_and_active():
+    """Moonlight-16B-A3B: about 16.0B parameters, about 2.9B a token (6
+    of 64 routed experts, the shared ones, the dense layer, attention,
+    embedding and head)."""
+    cfg = get_config("moonlight-16b-a3b")
+    assert round(cfg.param_count() / 1e9, 1) == 16.0
+    assert round(cfg.active_param_count() / 1e9, 1) == 2.9
 
 
 # ------------------------------------------------ decode == prefill ---------

@@ -165,6 +165,37 @@ def test_starcoder2_7b_prefill_chunk_fits_one_chip(one_chip, chip_mesh):
                                                   769, 48))
 
 
+def test_moonlight_paged_step_and_chunk_compile(one_chip, chip_mesh):
+    """Latent attention and dropless routing: the paged decode step and
+    the prefill chunk at moonlight-16b-a3b's published widths, one chip's
+    16 of 64 experts, depth cut to 3 (the dense layer and two expert
+    layers): 8 slots over 256 latent blocks of 16 positions, bf16."""
+    from repro.models import transformer
+    cfg = serving_config(replace(get_config("moonlight-16b-a3b"), n_layers=3,
+                                 experts_held=(0, 16)))
+    params = _on(_param_shapes(cfg), one_chip)
+    arenas = _on(jax.eval_shape(
+        lambda: transformer.init_paged_arena(cfg, 256, 16)), one_chip)
+    assert arenas["latent"].shape == (3, 256, 16, 512)
+    assert arenas["rope"].shape == (3, 256, 16, 64)
+    bt = jax.ShapeDtypeStruct((8, 128), jnp.int32, sharding=one_chip)
+    tok = jax.ShapeDtypeStruct((8, 1), jnp.int32, sharding=one_chip)
+    cl = jax.ShapeDtypeStruct((8,), jnp.int32, sharding=one_chip)
+    step = jax.jit(make_paged_serve_step(cfg, rules_for(cfg, chip_mesh)))
+    lowered = step.lower(params, arenas, bt, tok, cl)
+    hits = lowered.out_info[2]
+    assert hits.shape == (2, 16) and hits.dtype == jnp.int32
+    compiled = lowered.compile()
+    assert _peak_bytes(compiled) < V5E_HBM_BYTES
+    # the latent arena enters and leaves in the row-major layout the step
+    # computes in, so no step converts the whole arena (a 576-wide entry
+    # did, twice a step)
+    entry_layout = compiled.as_text().split("\n", 1)[0]
+    assert "bf16[3,256,16,512]{3,2,1,0:" in entry_layout
+    _assert_chunk_program(*_compile_prefill_chunk(cfg, chip_mesh, one_chip,
+                                                  256, 128))
+
+
 def test_invoke_step_compiles(one_chip, gsys):
     """A device-initiated syscall: the jitted step's io_callback (ordered,
     as WORK_ITEM batches are) lowers and compiles for the chip."""

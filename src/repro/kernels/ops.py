@@ -68,22 +68,19 @@ def paged_decode_attention(q, k_pages, v_pages, block_tables, lens, *,
                                       interpret=interpret)
 
 
-def update_kv_buffer(k_pages, v_pages, k_new, v_new, slots):
+def update_kv_buffer(pages, layer, blocks, offsets, new):
     """Paged KV-cache append (lite_llama's ``update_kv_buffer`` surface):
-    scatter one new token's K/V per sequence into flat arena slots.
+    write new entries in place into one layer of a stacked block arena.
 
-    k_pages/v_pages [NB,BS,KV,hd]; k_new/v_new [B,KV,hd]; slots [B] int32
-    flat slot index (block_id * BS + offset within the block). Multiple
-    rows may only alias a slot inside the pool's null block (inactive
-    batch rows), where any write order is acceptable; out-of-range slots
-    are dropped.
+    pages [L,NB,BS,...]; layer a scalar; blocks/offsets [B,S] int32, the
+    block and the offset within it of each new entry; new [B,S,...]. Only
+    those entries change, with no slice of the layer made. Multiple
+    entries may only alias a slot inside the pool's null block (inactive
+    batch rows), where any write order is acceptable; an entry aimed out
+    of range (a block past the arena) is dropped.
     """
-    NB, BS, KV, hd = k_pages.shape
-    kf = k_pages.reshape(NB * BS, KV, hd)
-    vf = v_pages.reshape(NB * BS, KV, hd)
-    kf = kf.at[slots].set(k_new.astype(kf.dtype), mode="drop")
-    vf = vf.at[slots].set(v_new.astype(vf.dtype), mode="drop")
-    return kf.reshape(NB, BS, KV, hd), vf.reshape(NB, BS, KV, hd)
+    return pages.at[layer, blocks, offsets].set(new.astype(pages.dtype),
+                                                 mode="drop")
 
 
 def mamba2_ssd(x, dt, A, Bm, Cm, *, chunk=64, interpret=None):

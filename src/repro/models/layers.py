@@ -162,56 +162,80 @@ def decode_attention_xla(q, k_cache, v_cache, cache_len, *, scale=None):
     return o.reshape(B, 1, H, hd).astype(q.dtype)
 
 
-def paged_attention_xla(q, k_pages, v_pages, block_tables, lens, *,
+def _row_pages(pages, layer, block_tables):
+    """Each row's pages of layer ``layer`` of the stacked arena
+    [L,NB,BS,...], gathered through its block table [B,MB] into
+    [B, MB*BS, ...], with no slice of the layer made."""
+    g = pages[layer, block_tables]
+    B, MB, BS = g.shape[:3]
+    return g.reshape(B, MB * BS, *g.shape[3:])
+
+
+def paged_attention_xla(q, k_pages, v_pages, layer, block_tables, lens, *,
                         scale=None):
-    """Single-token decode against the genesys.pagedkv block arena — the
-    XLA reference the Pallas split-KV kernel must match. q [B,1,H,hd];
-    k_pages/v_pages [NB,BS,KV,hd]; block_tables [B,MB] int32 (pad with the
-    pool's null block; padded positions are masked by ``lens``); lens [B].
+    """Single-token decode against layer ``layer`` of the genesys.pagedkv
+    block arena. q [B,1,H,hd]; k_pages/v_pages [L,NB,BS,KV,hd];
+    block_tables [B,MB] int32 (pad with the pool's null block; padded
+    positions are masked by ``lens``); lens [B].
 
     Gathers each sequence's pages into a [B, MB*BS, KV, hd] view and runs
-    the masked decode softmax — the logical computation the kernel
-    performs in place through the block table.
+    the masked decode softmax — the logical computation the Pallas
+    split-KV kernel performs in place through the block table.
     """
-    B, _, H, hd = q.shape
-    NB, BS, KV, _ = k_pages.shape
-    MB = block_tables.shape[1]
-    kd = k_pages[block_tables].reshape(B, MB * BS, KV, hd)
-    vd = v_pages[block_tables].reshape(B, MB * BS, KV, hd)
+    kd = _row_pages(k_pages, layer, block_tables)
+    vd = _row_pages(v_pages, layer, block_tables)
     if k_pages.dtype == jnp.int8:
         kd = kd.astype(q.dtype) / QSCALE
         vd = vd.astype(q.dtype) / QSCALE
     return decode_attention_xla(q, kd, vd, lens, scale=scale)
 
 
-def paged_chunk_attention_xla(q, k_pages, v_pages, block_tables, positions,
-                              *, scale=None):
+def paged_chunk_attention_xla(q, k_pages, v_pages, layer, block_tables,
+                              positions, *, scale=None):
     """Causal attention of S queries per row against the genesys.pagedkv
     block arena: the query at ``positions[b, i]`` attends every position
     of its row up to and including its own. The masked float32 softmax of
     :func:`decode_attention_xla`, over the same gathered pages as
     :func:`paged_attention_xla`. q [B,S,H,hd]; k_pages/v_pages
-    [NB,BS,KV,hd]; block_tables [B,MB]; positions [B,S]."""
+    [L,NB,BS,KV,hd], read at ``layer``; block_tables [B,MB]; positions
+    [B,S]."""
     B, S, H, hd = q.shape
-    NB, BS, KV, _ = k_pages.shape
-    MB = block_tables.shape[1]
+    kd = _row_pages(k_pages, layer, block_tables)
+    vd = _row_pages(v_pages, layer, block_tables)
+    KV = kd.shape[2]
     G = H // KV
     scale = scale if scale is not None else hd ** -0.5
-    kd = k_pages[block_tables].reshape(B, MB * BS, KV, hd)
-    vd = v_pages[block_tables].reshape(B, MB * BS, KV, hd)
     if k_pages.dtype == jnp.int8:
         kd = kd.astype(q.dtype) / QSCALE
         vd = vd.astype(q.dtype) / QSCALE
     qr = q.reshape(B, S, KV, G, hd)
     s = jnp.einsum("bqkgh,bskh->bkgqs", qr, kd,
                    preferred_element_type=jnp.float32) * scale
-    pos = jnp.arange(MB * BS, dtype=jnp.int32)
+    pos = jnp.arange(kd.shape[1], dtype=jnp.int32)
     mask = pos[None, None, :] <= positions[:, :, None]      # [B,S,MB*BS]
     s = jnp.where(mask[:, None, None], s, -jnp.inf)
     p = jax.nn.softmax(s, axis=-1)
     o = jnp.einsum("bkgqs,bskh->bqkgh", p.astype(vd.dtype), vd,
                    preferred_element_type=jnp.float32)
     return o.reshape(B, S, H, hd).astype(q.dtype)
+
+
+def _table_blocks(block_tables, positions, block_size):
+    """The arena block that holds each position [B,S] of a row, through
+    the row's table [B,MB] (a position past the table reads its last
+    entry; only padding lies there)."""
+    col = jnp.minimum(positions // block_size, block_tables.shape[1] - 1)
+    return jnp.take_along_axis(block_tables, col, axis=1)
+
+
+def _drop_padding(blk, n_valid, past):
+    """Aim the padding tokens (i >= n_valid) of each row at block
+    ``past``, outside the arena, so a ``mode="drop"`` scatter drops their
+    entries."""
+    if n_valid is None:
+        return blk
+    i = jnp.arange(blk.shape[1], dtype=jnp.int32)
+    return jnp.where(i[None] < n_valid[:, None], blk, past)
 
 
 # ------------------------------------------------------------ attention -----
@@ -266,50 +290,33 @@ def attention(p, cfg, rules, x, *, positions, causal=True, kv_x=None,
 
     new_cache = None
     if paged_cache is not None and kv_x is None:
-        # the genesys.pagedkv block arena [L,NB,BS,KV,hd], chosen by the
-        # static length S. S == 1 (the decode step): WRITE the new token's
-        # K/V at its block-table slot (the update_kv_buffer scatter at
-        # block_tables[b, cl//BS]*BS + cl%BS), then attend through the
-        # block table with per-row lens = cache_len + 1. Inactive batch
-        # rows carry all-null block tables and cache_len 0, so their
-        # writes land in the pool's null block and their outputs are
-        # garbage nobody reads (slot-masked continuous batching,
-        # serving/engine.py). S > 1 (a prompt chunk): write the S tokens'
-        # K/V at positions cache_len + i, padding rows (i >= n_valid) at
-        # an out-of-range slot the scatter drops, then attend each query
+        # the genesys.pagedkv block arenas [L,NB,BS,KV,hd], updated in
+        # place: the S new tokens' K/V are scattered straight into
+        # arena[li, block, offset] through each row's table, then every
+        # row's pages are gathered as arena[li, block_tables]; no layer
+        # is sliced out or written back. Write before read, so the query
+        # sees its own token through the table. Inactive batch rows carry
+        # all-null block tables and cache_len 0, so their writes land in
+        # the pool's null block and their outputs are garbage nobody
+        # reads (slot-masked continuous batching, serving/engine.py); a
+        # prompt chunk's padding (i >= n_valid) is aimed past the arena
+        # and dropped. S == 1 (the decode step) attends with per-row
+        # lens = cache_len + 1; S > 1 (a prompt chunk) attends each query
         # causally over its row's pages.
         kp, vp, bt, li = paged_cache
-        BS = kp.shape[2]
-        quant = kp.dtype == jnp.int8
-        kp_l = jax.lax.dynamic_index_in_dim(kp, li, axis=0, keepdims=False)
-        vp_l = jax.lax.dynamic_index_in_dim(vp, li, axis=0, keepdims=False)
-        if quant:
+        NB, BS = kp.shape[1:3]
+        k_w, v_w = k, v
+        if kp.dtype == jnp.int8:
             k_w = jnp.clip(jnp.round(k * QSCALE), -127, 127).astype(jnp.int8)
             v_w = jnp.clip(jnp.round(v * QSCALE), -127, 127).astype(jnp.int8)
+        blk = _drop_padding(_table_blocks(bt, positions, BS), n_valid, NB)
+        off = positions % BS
+        kp = kernel_ops.update_kv_buffer(kp, li, blk, off, k_w)
+        vp = kernel_ops.update_kv_buffer(vp, li, blk, off, v_w)
+        if x.shape[1] == 1:
+            out = paged_attention_xla(q, kp, vp, li, bt, cache_len + 1)
         else:
-            k_w = k.astype(kp.dtype)
-            v_w = v.astype(vp.dtype)
-        B, S = x.shape[:2]
-        if S == 1:
-            slot = (bt[jnp.arange(B), cache_len // BS] * BS
-                    + cache_len % BS)
-            kp_l, vp_l = kernel_ops.update_kv_buffer(kp_l, vp_l, k_w[:, 0],
-                                                     v_w[:, 0], slot)
-            out = paged_attention_xla(q, kp_l, vp_l, bt, cache_len + 1)
-        else:
-            i = jnp.arange(S, dtype=jnp.int32)
-            pos = cache_len[:, None] + i                         # [B,S]
-            blk = jnp.take_along_axis(
-                bt, jnp.minimum(pos // BS, bt.shape[1] - 1), axis=1)
-            slot = jnp.where(i[None] < n_valid[:, None],
-                             blk * BS + pos % BS, kp_l.shape[0] * BS)
-            KVh, hd = k_w.shape[2:]
-            kp_l, vp_l = kernel_ops.update_kv_buffer(
-                kp_l, vp_l, k_w.reshape(B * S, KVh, hd),
-                v_w.reshape(B * S, KVh, hd), slot.reshape(-1))
-            out = paged_chunk_attention_xla(q, kp_l, vp_l, bt, pos)
-        kp = jax.lax.dynamic_update_slice_in_dim(kp, kp_l[None], li, axis=0)
-        vp = jax.lax.dynamic_update_slice_in_dim(vp, vp_l[None], li, axis=0)
+            out = paged_chunk_attention_xla(q, kp, vp, li, bt, positions)
         new_cache = (kp, vp)
     elif carried_cache is not None and kv_x is None:
         # decode against a CARRIED stacked cache [L,B,S,KV,hd] (§Perf
@@ -447,11 +454,12 @@ def mla_attention(p, cfg, rules, x, *, positions, n_valid=None,
                   entries=None, block_tables=None, li=None):
     """Latent attention -> ``(out, entries')``. Without ``entries``,
     causal over the S tokens themselves (``entries'`` None). With them,
-    each query attends over its row's entries of layer ``li`` up to its
-    own position, the S new entries among them, which are then written
-    at their slots: ``entries`` is :func:`mla_entries`' pair as paged
-    arenas [L,NB,BS,n] addressed through ``block_tables`` [B,MB], or,
-    without tables, a carried cache [L,B,Smax,n]. Padding tokens
+    the S new entries are first written in place at their slots of layer
+    ``li``, then each query attends over its row's entries of that layer
+    up to its own position: ``entries`` is :func:`mla_entries`' pair as
+    paged arenas [L,NB,BS,n] addressed through ``block_tables`` [B,MB]
+    (gathered at the layer, never sliced out), or, without tables, a
+    carried cache [L,B,Smax,n]. Padding tokens
     (i >= n_valid) and positions past the cache are aimed out of range,
     so dropped; inactive rows write into the null block, as in the paged
     GQA branch of :func:`attention`. S == 1 (decode) runs the absorbed
@@ -468,34 +476,22 @@ def mla_attention(p, cfg, rules, x, *, positions, n_valid=None,
         kv, kpos = new, positions[:, None, :]
     else:
         NB, BS = entries["latent"].shape[1:3]
-        n_flat = NB * BS
         if block_tables is not None:
-            bt = block_tables
-            blk = jnp.take_along_axis(
-                bt, jnp.minimum(positions // BS, bt.shape[1] - 1), axis=1)
-            slot = blk * BS + positions % BS
+            blk = _table_blocks(block_tables, positions, BS)
+            off = positions % BS
         else:
-            slot = jnp.where(positions < BS,
-                             jnp.arange(B)[:, None] * BS + positions, n_flat)
-        if n_valid is not None:
-            i = jnp.arange(S, dtype=jnp.int32)
-            slot = jnp.where(i[None] < n_valid[:, None], slot, n_flat)
-        kv, out = {}, {}
-        for name, arena in entries.items():
-            a_l = jax.lax.dynamic_index_in_dim(arena, li, 0, keepdims=False)
-            n = a_l.shape[-1]
-            view = a_l if block_tables is None else \
-                a_l[block_tables].reshape(B, -1, n)
-            e = new[name].astype(a_l.dtype)
-            # the new entries join the row's view at their positions (a
-            # padding token's lies past every real query, so none sees it)
-            kv[name] = view.at[jnp.arange(B)[:, None], positions].set(
-                e, mode="drop").astype(dt)
-            flat = a_l.reshape(-1, n).at[slot.reshape(-1)].set(
-                e.reshape(-1, n), mode="drop")
-            out[name] = jax.lax.dynamic_update_slice_in_dim(
-                arena, flat.reshape(a_l.shape)[None], li, axis=0)
-        entries = out
+            # the carried cache: row b's entries are "block" b
+            blk = jnp.broadcast_to(jnp.arange(B)[:, None], positions.shape)
+            off = positions
+        blk = _drop_padding(blk, n_valid, NB)
+        # written in place before the read, so the view holds the new
+        # entries (a padding token's lies past every real query anyway)
+        entries = {name: kernel_ops.update_kv_buffer(a, li, blk, off,
+                                                     new[name])
+                   for name, a in entries.items()}
+        kv = {name: (a[li] if block_tables is None else
+                     _row_pages(a, li, block_tables)).astype(dt)
+              for name, a in entries.items()}
         kpos = jnp.arange(kv["latent"].shape[1], dtype=jnp.int32)[None, None]
     mask = kpos <= positions[:, :, None]                   # [B,S,T]
     form = mla_absorbed if S == 1 and entries is not None else mla_expanded

@@ -62,6 +62,10 @@ class EngineStats:
     prefill_steps_saved: int = 0  # prompt tokens skipped via prefix reuse
     prefill_chunks: int = 0      # prefill_chunk dispatches
     prefill_chunk_tokens: int = 0  # prompt tokens the chunks wrote
+    # bytes the compiled step and chunk alias from input to output (set
+    # once by make_engine): twice the arenas' bytes when both update
+    # them in place, 0 without jit or where the backend reports none
+    arena_alias_bytes: int = 0
 
     def occupancy(self) -> float:
         return self.step_slots / max(1, self.steps)
@@ -373,16 +377,44 @@ class ContinuousBatchEngine:
         return out
 
 
+def compile_programs(step, chunk, params, arenas, *, n_slots: int,
+                     max_blocks: int, sharding=None):
+    """Jit the paged decode step and the prefill chunk with the arenas
+    donated (argument 1), so both update them in place, and compile each
+    at its one shape: ``n_slots`` decode rows, ``max_blocks`` table
+    columns, ``CHUNK`` prompt tokens. ``sharding`` places the int32
+    inputs (default: uncommitted, as the engine's numpy-built ones are).
+    -> (compiled step, compiled chunk)."""
+    def i32(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=sharding)
+
+    step = jax.jit(step, donate_argnums=1).lower(
+        params, arenas, i32(n_slots, max_blocks), i32(n_slots, 1),
+        i32(n_slots)).compile()
+    chunk = jax.jit(chunk, donate_argnums=1).lower(
+        params, arenas, i32(1, max_blocks), i32(1, CHUNK), i32(1),
+        i32(1)).compile()
+    return step, chunk
+
+
+def alias_bytes(compiled) -> int:
+    """Bytes a compiled program aliases from its inputs to its outputs
+    (0 where the backend gives no memory analysis)."""
+    m = compiled.memory_analysis()
+    return int(getattr(m, "alias_size_in_bytes", 0) or 0)
+
+
 def make_engine(cfg, rules, params, *, n_slots: int, n_blocks: int,
                 block_size: int, max_blocks_per_seq: int | None = None,
                 gsys=None, spill_path: str | None = None, stats=None,
                 jit=True):
-    """Build the paged pool, device arenas and a jitted paged serve step
-    into a ready :class:`ContinuousBatchEngine`. With ``gsys`` the pool's
+    """Build the paged pool, device arenas and the jitted paged serve step
+    and prefill chunk into a ready :class:`ContinuousBatchEngine`. Both
+    programs are compiled here, at their one shape, so a window's first
+    step and first prompt wait for no compile; the arenas are donated to
+    both, so no second arena copy is ever live. With ``gsys`` the pool's
     blocks are carved through genesys (mmap/touch/madvise residency, and
     — given ``spill_path`` — PWRITE64 spill + PREAD64_FIXED revival)."""
-    import jax
-
     from repro.models import transformer
     from repro.train.steps import (make_paged_prefill_chunk,
                                    make_paged_serve_step)
@@ -392,19 +424,15 @@ def make_engine(cfg, rules, params, *, n_slots: int, n_blocks: int,
     max_blocks = max_blocks_per_seq or n_blocks // 2
     step = make_paged_serve_step(cfg, rules)
     chunk = make_paged_prefill_chunk(cfg, rules)
+    aliased = 0
     if jit:
-        step = jax.jit(step)
-        # compiled here, at its one shape: a window's first prompt must
-        # not wait for it (the arenas are donated, so no second arena
-        # copy is ever live)
-        i32 = jnp.int32
-        chunk = jax.jit(chunk, donate_argnums=1).lower(
-            params, arenas, jnp.zeros((1, max_blocks), i32),
-            jnp.zeros((1, CHUNK), i32), jnp.zeros((1,), i32),
-            jnp.zeros((1,), i32)).compile()
+        step, chunk = compile_programs(step, chunk, params, arenas,
+                                       n_slots=n_slots, max_blocks=max_blocks)
+        aliased = alias_bytes(step) + alias_bytes(chunk)
     eng = ContinuousBatchEngine(
         step, params, arenas, pool, prefill_chunk=chunk, n_slots=n_slots,
         max_blocks_per_seq=max_blocks, stats=stats)
+    eng.counters.add(arena_alias_bytes=aliased)
     if gsys is not None:
         pool.bind_genesys(gsys, block_bytes=eng.block_bytes(),
                           spill_path=spill_path)

@@ -171,28 +171,22 @@ def test_paged_decode_garbage_beyond_lens_is_masked():
 
 
 def test_update_kv_buffer_scatters_and_drops():
-    """Paged append: each row's (k,v) lands at its flat slot
-    (block * BS + offset); out-of-range slots (the null-block parking of
-    inactive batch rows) drop instead of wrapping."""
-    NB, BS, KV, hd, B = 5, 4, 2, 8, 3
-    ks = jax.random.split(jax.random.PRNGKey(9), 4)
-    kp0 = jax.random.normal(ks[0], (NB, BS, KV, hd))
-    vp0 = jax.random.normal(ks[1], (NB, BS, KV, hd))
-    k_new = jax.random.normal(ks[2], (B, KV, hd))
-    v_new = jax.random.normal(ks[3], (B, KV, hd))
-    slots = jnp.asarray([6, 13, NB * BS + 1])        # last is out of range
-    kp, vp = ops.update_kv_buffer(kp0, vp0, k_new, v_new, slots)
-    kf, vf = (np.asarray(kp).reshape(NB * BS, KV, hd),
-              np.asarray(vp).reshape(NB * BS, KV, hd))
-    np.testing.assert_allclose(kf[6], np.asarray(k_new)[0])
-    np.testing.assert_allclose(vf[13], np.asarray(v_new)[1])
-    untouched = [i for i in range(NB * BS) if i not in (6, 13)]
-    np.testing.assert_allclose(
-        kf[untouched],
-        np.asarray(kp0).reshape(NB * BS, KV, hd)[untouched])
-    np.testing.assert_allclose(
-        vf[untouched],
-        np.asarray(vp0).reshape(NB * BS, KV, hd)[untouched])
+    """Paged append into a stacked arena: each entry lands at (layer,
+    block, offset) of its row and nothing else moves, in that layer or any
+    other; an entry aimed past the arena (a chunk's padding) drops
+    instead of wrapping."""
+    L, NB, BS, KV, hd = 3, 5, 4, 2, 8
+    ks = jax.random.split(jax.random.PRNGKey(9), 2)
+    kp0 = jax.random.normal(ks[0], (L, NB, BS, KV, hd))
+    new = jax.random.normal(ks[1], (3, 1, KV, hd))
+    blocks = jnp.asarray([[1], [3], [NB + 1]])       # last is out of range
+    offsets = jnp.asarray([[2], [1], [0]])
+    kp = np.asarray(ops.update_kv_buffer(kp0, 1, blocks, offsets, new))
+    np.testing.assert_allclose(kp[1, 1, 2], np.asarray(new)[0, 0])
+    np.testing.assert_allclose(kp[1, 3, 1], np.asarray(new)[1, 0])
+    moved = np.zeros((L, NB, BS), bool)
+    moved[1, 1, 2] = moved[1, 3, 1] = True
+    np.testing.assert_allclose(kp[~moved], np.asarray(kp0)[~moved])
 
 
 def test_moe_gmm_skewed_experts():

@@ -383,3 +383,110 @@ def test_chunked_prefill_compiles_nothing_after_warm_up(chunk_rig):
             jax.monitoring.unregister_event_duration_listener(listen)
     assert eng.stats.prefill_chunks == 3
     assert compiles == []
+
+
+# ---------------------------------------------- arenas updated in place -----
+
+FAMILIES = ["internlm2-20b", "moonlight-16b-a3b"]     # {k, v}; {latent, rope}
+
+
+def _family(name, mesh):
+    from repro.configs import get_config
+    from repro.models.registry import get_api
+    from repro.sharding import rules_for
+    cfg = _f32(get_config(name).reduced())
+    params, _ = get_api(cfg).init(jax.random.PRNGKey(2), cfg)
+    return cfg, rules_for(cfg, mesh), params
+
+
+def _changed(before, after):
+    """{entry: [L, NB, BS] bool}: where an arena's entries differ."""
+    return {k: np.any(np.asarray(after[k]) != a, axis=tuple(
+        range(3, a.ndim))) for k, a in before.items()}
+
+
+@pytest.mark.parametrize("name", FAMILIES)
+def test_step_and_chunk_write_only_their_new_entries(mesh11, name):
+    """The donated decode step writes each seated row's new token at its
+    table slot and nothing else but the null block (the inactive rows'
+    target); a chunk writes its n_valid prompt positions and drops its
+    padding. On random arenas, so any other write would show."""
+    from repro.models import transformer
+    from repro.serving.engine import compile_programs
+    from repro.train.steps import (make_paged_prefill_chunk,
+                                   make_paged_serve_step)
+    cfg, rules, params = _family(name, mesh11)
+    NB, MB = 16, 6
+    rng = np.random.default_rng(4)
+    before = {k: rng.normal(size=a.shape).astype(np.float32) for k, a in
+              transformer.init_paged_arena(cfg, NB, BS).items()}
+    with mesh11:
+        step, chunk = compile_programs(
+            make_paged_serve_step(cfg, rules),
+            make_paged_prefill_chunk(cfg, rules), params,
+            {k: jnp.asarray(a) for k, a in before.items()}, n_slots=3,
+            max_blocks=MB)
+        # rows 0 and 2 seated at positions 9 and 5; row 1 free (all null)
+        bt = np.zeros((3, MB), np.int32)
+        bt[0, :3], bt[2, :2] = [3, 4, 5], [7, 8]
+        out = step(params, {k: jnp.asarray(a) for k, a in before.items()},
+                   jnp.asarray(bt), jnp.asarray([[11], [0], [13]], np.int32),
+                   jnp.asarray([9, 0, 5], np.int32))
+        got = _changed(before, out[1])
+        want = np.zeros((NB, BS), bool)
+        want[5, 9 % BS] = want[8, 5 % BS] = True
+        for k, ch in got.items():
+            # the null block: only the free row's slot, offset 0
+            assert (ch[:, NULL_BLOCK + 1:] == want[None, 1:]).all(), k
+            assert not ch[:, NULL_BLOCK, 1:].any(), k
+        # a chunk of 7 prompt tokens at positions 4..10 of a 6-block row
+        row = np.asarray([[2, 6, 9, 10, 11, 12]], np.int32)
+        toks = np.zeros((1, CHUNK), np.int32)
+        toks[0, :7] = np.arange(1, 8)
+        after = chunk(params, {k: jnp.asarray(a) for k, a in before.items()},
+                      jnp.asarray(row), jnp.asarray(toks),
+                      jnp.asarray([4], np.int32), jnp.asarray([7], np.int32))
+    want = np.zeros((NB, BS), bool)
+    for p in range(4, 11):
+        want[row[0, p // BS], p % BS] = True
+    for k, ch in _changed(before, after).items():
+        assert (ch == want[None]).all(), k
+
+
+@pytest.mark.parametrize("name", FAMILIES)
+def test_donated_engine_serves_the_undonated_engines_tokens(mesh11, gsys,
+                                                          name):
+    """An engine whose compiled programs update the arenas in place serves
+    the same tokens as one running the same functions unjitted, over
+    staggered admissions, prompts written in chunks (one of two chunks)
+    and retirements that free slots for the next request. Its telemetry
+    counts both programs' aliased arenas."""
+    from repro.serving.engine import make_engine
+    cfg, rules, params = _family(name, mesh11)
+    rng = np.random.default_rng(11)
+    reqs = [(rng.integers(1, cfg.vocab_size, size=n).astype(np.int32), b)
+            for n, b in [(1, 4), (6, 3), (CHUNK + 9, 2), (3, 5), (2, 2)]]
+    served = []
+    for jit in (True, False):
+        eng = make_engine(cfg, rules, params, n_slots=2, n_blocks=160,
+                          block_size=BS, max_blocks_per_seq=72, jit=jit,
+                          gsys=gsys if jit else None)
+        arena_bytes = sum(a.size * a.dtype.itemsize
+                          for a in eng.arenas.values())
+        assert eng.stats.arena_alias_bytes == (2 * arena_bytes if jit else 0)
+        done, pending = {}, list(enumerate(reqs))
+        with mesh11:
+            while pending or eng.n_active:
+                while pending and eng.admit(pending[0][1][0],
+                                            pending[0][1][1],
+                                            meta=pending[0][0]):
+                    pending.pop(0)
+                for meta, gen in eng.step():
+                    done[meta] = gen
+        assert eng.stats.prefill_chunks == 5
+        assert eng.pool.stats.blocks_in_use == 0
+        served.append(done)
+    assert served[0] == served[1]
+    engine = gsys.telemetry()["serving"]["engine"]
+    assert engine["arena_alias_bytes"] == 2 * arena_bytes
+    assert sorted(served[0]) == list(range(len(reqs)))

@@ -7,6 +7,7 @@ one process may load the TPU library, and every test worker imports this
 file. Nothing here runs on a device; shapes stand in for arrays.
 """
 import os
+import re
 from dataclasses import replace
 
 import jax
@@ -19,7 +20,7 @@ from repro.configs import get_config
 from repro.launch.serve import serving_config
 from repro.models.registry import get_api
 from repro.sharding import rules_for
-from repro.serving.engine import CHUNK
+from repro.serving.engine import CHUNK, alias_bytes, compile_programs
 from repro.train.steps import (make_paged_prefill_chunk, make_paged_serve_step,
                                make_serve_step)
 
@@ -194,6 +195,49 @@ def test_moonlight_paged_step_and_chunk_compile(one_chip, chip_mesh):
     assert "bf16[3,256,16,512]{3,2,1,0:" in entry_layout
     _assert_chunk_program(*_compile_prefill_chunk(cfg, chip_mesh, one_chip,
                                                   256, 128))
+
+
+# (configuration, depth, the cell's arena blocks, slots, blocks a row):
+# arenas larger than the chip's on-chip memory, which a small arena is
+# staged through (its copies would blur what this checks)
+IN_PLACE_CASES = [("internlm2-20b", 4, 2817, 32, 88),
+                  ("moonlight-16b-a3b", 27, 4225, 48, 88)]
+
+
+@pytest.mark.parametrize("name,layers,n_blocks,slots,row", IN_PLACE_CASES,
+                         ids=[c[0] for c in IN_PLACE_CASES])
+def test_engine_programs_update_the_arenas_in_place(one_chip, chip_mesh,
+                                                    name, layers, n_blocks,
+                                                    slots, row):
+    """The decode step and the prefill chunk, jitted and compiled as
+    ``make_engine`` does (arenas donated), at a cell's arena size: both
+    alias the arenas to their outputs, so no step copies them in, and no
+    instruction has a whole layer's arena shape ([NB,BS,...],
+    [1,NB,BS,...] or [NB*BS,...]), so no layer is sliced out, scattered
+    into or written back: each program writes only its new entries and
+    gathers its rows' pages at (layer, block)."""
+    from repro.models import transformer
+    held = {"experts_held": (0, 16)} if name.startswith("moonlight") else {}
+    cfg = serving_config(replace(get_config(name), n_layers=layers, **held))
+    rules = rules_for(cfg, chip_mesh)
+    arenas = _on(jax.eval_shape(
+        lambda: transformer.init_paged_arena(cfg, n_blocks, 16)), one_chip)
+    arena_bytes = sum(x.size * x.dtype.itemsize
+                      for x in jax.tree_util.tree_leaves(arenas))
+    whole = re.compile("|".join(
+        rf"\[(1,)?{n_blocks},16{w}\]|\[{n_blocks * 16}{w}\]"
+        for w in {"".join(f",{d}" for d in a.shape[3:])
+                  for a in jax.tree_util.tree_leaves(arenas)}))
+    for compiled in compile_programs(
+            make_paged_serve_step(cfg, rules),
+            make_paged_prefill_chunk(cfg, rules), _on(_param_shapes(cfg),
+                                                      one_chip),
+            arenas, n_slots=slots, max_blocks=row, sharding=one_chip):
+        assert alias_bytes(compiled) >= arena_bytes
+        assert _peak_bytes(compiled) < V5E_HBM_BYTES
+        found = [ln.strip()[:120] for ln in compiled.as_text().splitlines()
+                 if whole.search(ln)]
+        assert not found, found[:4]
 
 
 def test_invoke_step_compiles(one_chip, gsys):
